@@ -26,8 +26,8 @@ asserts relax (correctness asserts never do).
 
 Compile telemetry (``EngineConfig.log_compiles``): the delta arm runs with
 the retrace recorder on. The sentinel-padded pow2 edge buckets must keep
-the edge-shape kernels (``msbfs_dist`` / ``msbfs_set_dist`` /
-``walk_counts``) warm across every in-bucket round — asserted
+the edge-shape kernels (``msbfs_dist`` / ``msbfs_set_dist``) warm
+across every in-bucket round — asserted
 ``warm_retraces == 0`` at *every* scale (CI wires this smoke). A final
 bucket-crossing delta (inserts pushing ``m`` past its pow2 bucket)
 measures the one-off retrace cost and the warm-vs-cold batch wall.
@@ -52,7 +52,7 @@ from .common import record
 # kernels whose traced shapes depend on the device edge lists: the ones
 # the pow2 sentinel buckets exist to keep warm (enumeration caps are
 # value-planned and may legitimately re-bucket as the workload drifts)
-EDGE_KERNELS = frozenset({"msbfs_dist", "msbfs_set_dist", "walk_counts"})
+EDGE_KERNELS = frozenset({"msbfs_dist", "msbfs_set_dist"})
 
 
 def _edge_arrays(g: Graph):

@@ -11,21 +11,17 @@ gate them:
     roof from ``launch.roofline.HW`` (the deploy target the Pallas path
     is tiled for). Interpret mode is a correctness backend, not a
     performance proxy, so it is never timed here.
-  * **dispatch counts** — the point of the fused ``msbfs_step`` kernel is
-    collapsing the per-level expand → dedup → distance-write chain into
-    ONE device dispatch. Both arms of one MS-BFS level are traced and
-    their jaxpr equations counted (pallas_call bodies count as one);
+  * **dispatch counts** — one MS-BFS level on the packed route (the
+    ``msbfs_step`` expand + dedup, then the ``msbfs_count`` distance
+    kernel) against the segment-op level of the edge-list sweep. Both
+    arms are traced and their jaxpr equations counted (pallas_call
+    bodies count as one);
     the jnp arm is additionally compiled and its HLO entry-computation
     op count recorded (``launch.hlo_analysis.count_entry_ops``). These
     are deterministic, hardware-independent integers — gateable in CI.
   * **warm retraces** — the packed sweeps run twice on identical shapes
     under the compile recorder; the second pass must add zero compiles
     (the zero-warm-retrace guarantee must survive the kernel route).
-
-The VMEM tile plan for ``msbfs_step`` is derived from the roofline
-constants: the (block_v, block_w) defaults must keep a tile's working set
-(ELL rows + full frontier column panel + dist tile) comfortably inside a
-v5e core's ~128 MiB/8 VMEM share.
 """
 from __future__ import annotations
 
@@ -74,13 +70,13 @@ def _dispatch_counts(n: int, D: int, S: int, seed: int = 0) -> dict:
 
     The jnp arm is one level of :func:`repro.core.msbfs.msbfs_dist`
     (expand + dedup + distance write as separate segment/mask ops); the
-    fused arm is the same level through ``msbfs_step`` (interpret mode —
+    fused arm is the same level on the packed route (interpret mode —
     the dispatch shape is identical to the compiled TPU kernel, only the
     body execution differs). Both jaxpr-eqn counts come from the same
     tracer, so the comparison is apples-to-apples and deterministic.
     """
     from repro.core.msbfs import msbfs_hop
-    from repro.kernels.msbfs_expand.ops import msbfs_step
+    from repro.kernels.msbfs_expand.ops import msbfs_step, unreached_count
 
     rng = np.random.default_rng(seed)
     m = n * 4
@@ -94,7 +90,7 @@ def _dispatch_counts(n: int, D: int, S: int, seed: int = 0) -> dict:
     fr_w = jnp.asarray(rng.integers(0, 2**32, (n + 1, W), dtype=np.uint64)
                        .astype(np.uint32))
     vis_w = fr_w[:n]
-    dist_w = jnp.asarray(rng.integers(0, 9, (n, W * 32)).astype(np.int8))
+    count_w = jnp.asarray(rng.integers(0, 9, (W * 32, n)).astype(np.int8))
 
     def level_jnp(frontier, dist):
         reached = (dist < jnp.int8(9)).astype(jnp.int8)
@@ -103,14 +99,14 @@ def _dispatch_counts(n: int, D: int, S: int, seed: int = 0) -> dict:
         dist = jnp.where(new.astype(bool), jnp.int8(3), dist)
         return new.at[n].set(0), dist
 
-    def level_fused(frontier, visited, dist):
-        f, v, d = msbfs_step(ell[:n], frontier, visited, dist, 3,
-                             backend="interpret")
-        return jnp.concatenate([f, jnp.zeros((1, W), jnp.uint32)]), v, d
+    def level_fused(frontier, visited, count):
+        f, v = msbfs_step(ell[:n], frontier, visited, backend="interpret")
+        count = unreached_count(v, count, backend="interpret")
+        return jnp.concatenate([f, jnp.zeros((1, W), jnp.uint32)]), v, count
 
     jnp_eqns = count_eqns(jax.make_jaxpr(level_jnp)(frontier8, dist8).jaxpr)
     fused_eqns = count_eqns(
-        jax.make_jaxpr(level_fused)(fr_w, vis_w, dist_w).jaxpr)
+        jax.make_jaxpr(level_fused)(fr_w, vis_w, count_w).jaxpr)
     # compiled footprint of the jnp arm (the fused arm's Pallas kernel
     # cannot lower off-TPU; its dispatch count IS the jaxpr count)
     hlo = jax.jit(level_jnp).lower(frontier8, dist8).compile().as_text()
@@ -147,29 +143,12 @@ def _warm_retraces(n: int, D: int, S: int) -> dict:
             "warm_compiles_by_kernel": rec.since(snap)}
 
 
-def _tile_plan(D: int) -> dict:
-    """VMEM working set of one msbfs_step tile at the default BlockSpec
-    (block_v x ELL rows, the full (V+1, block_w) frontier panel is
-    re-fetched per row tile — the frontier is the reuse-heavy operand, so
-    it is the one kept resident)."""
-    block_v, block_w = 256, 8
-    v_frontier = 200_000           # sizing vertex count for the panel term
-    tile = (block_v * D * 4                 # ELL idx rows
-            + (v_frontier + 1) * block_w * 4   # frontier panel (u32)
-            + block_v * block_w * 4 * 2     # visited in + out (u32)
-            + block_v * block_w * 32 * 2)   # dist in + out (i8)
-    vmem_share = 128 * 2**20 / 8
-    return {"block_v": block_v, "block_w": block_w,
-            "tile_bytes": tile, "vmem_share_bytes": int(vmem_share),
-            "fits_vmem": bool(tile <= vmem_share)}
-
-
 def main(scale: float = 1.0) -> dict:
     rng = np.random.default_rng(0)
     out: dict = {"ops": {}}
 
-    # fused MS-BFS level (jnp twin of msbfs_step): 200k vertices, deg-8
-    # ELL, 128 packed sources
+    # packed MS-BFS expand + dedup (msbfs_step, jnp): 200k vertices,
+    # deg-8 ELL, 128 packed sources
     from repro.kernels.msbfs_expand.ops import msbfs_step
     n, D, S = max(int(200_000 * scale), 4096), 8, 128
     W = S // 32
@@ -178,13 +157,10 @@ def main(scale: float = 1.0) -> dict:
     fr = jnp.asarray(rng.integers(0, 2**32, (n + 1, W), dtype=np.uint64)
                      .astype(np.uint32))
     vis = fr[:n]
-    dist = jnp.asarray(rng.integers(0, 9, (n, W * 32)).astype(np.int8))
-    f = jax.jit(lambda a, b, c: msbfs_step(ell[:n], a, b, c, 3,
-                                           backend="jnp"))
-    dt = _bench(f, fr, vis, dist)
-    # traffic: ELL rows + gathered frontier words + visited r/w + dist r/w
-    nbytes = (n * D * 4 + n * D * W * 4 + 2 * (2 * n * W * 4) +
-              2 * (n * W * 32))
+    f = jax.jit(lambda a, b: msbfs_step(ell[:n], a, b, backend="jnp"))
+    dt = _bench(f, fr, vis)
+    # traffic: ELL rows + gathered frontier words + visited r/w + new out
+    nbytes = n * D * 4 + n * D * W * 4 + 2 * n * W * 4 + n * W * 4
     out["ops"]["msbfs_step_jnp"] = _op_row(
         "msbfs_step_jnp", dt, nbytes,
         f"V={n};D={D};S={S};GTEPS={n * D * S / dt / 1e9:.2f}")
@@ -232,17 +208,6 @@ def main(scale: float = 1.0) -> dict:
         "path_overlap_jnp", dt, 2 * 4096 * 6 * 4 + 4096 * 4096 * 4,
         f"pairs={4096 * 4096};Mpairs_s={4096 * 4096 / dt / 1e6:.1f}")
 
-    # ELL SpMM (index walk-count DP step): 100k x deg16 x 128 feats
-    from repro.kernels.ell_spmm.ref import ell_spmm_ref
-    V, Dd, F = max(int(100_000 * scale), 4096), 16, 128
-    ellv = jnp.asarray(rng.integers(0, V + 1, (V, Dd)).astype(np.int32))
-    x = jnp.asarray(rng.standard_normal((V + 1, F)).astype(np.float32))
-    f = jax.jit(lambda e, xx: ell_spmm_ref(e, xx, "sum"))
-    dt = _bench(f, ellv, x)
-    out["ops"]["ell_spmm_jnp"] = _op_row(
-        "ell_spmm_jnp", dt, V * Dd * 4 + V * Dd * F * 4 + V * F * 4,
-        f"gflops={2 * V * Dd * F / dt / 1e9:.1f}")
-
     # chunked attention (flash twin): B4 S2048 H8 hd64
     from repro.models.transformer import chunked_attention
     q = jnp.asarray(rng.standard_normal((4, 2048, 8, 64)).astype(np.float32))
@@ -267,7 +232,6 @@ def main(scale: float = 1.0) -> dict:
     record("kernel_warm_retraces", out["warm_retraces"],
            str(out["warm_compiles_by_kernel"]))
 
-    out["tile_plan"] = _tile_plan(D)
     out["hw"] = {"hbm_bw": HW["hbm_bw"], "peak_flops": HW["peak_flops"]}
 
     dest = Path("results/BENCH_kernels.json")

@@ -112,29 +112,6 @@ def _mk_msbfs_set_dist_ell(backend: str, k: int):
             (ell, seed))
 
 
-def _mk_walk_counts(backend: str, k: int):
-    import jax.numpy as jnp
-    from ..core.index import walk_counts
-    n, m = 16, 8
-    esrc = jnp.zeros((m,), jnp.int32)
-    edst = jnp.zeros((m,), jnp.int32)
-    slack = jnp.zeros((n + 1,), jnp.int8)
-    return (lambda a, b, s: walk_counts(a, b, jnp.int32(0), s,
-                                        n=n, budget=k),
-            (esrc, edst, slack))
-
-
-def _mk_walk_counts_ell(backend: str, k: int):
-    import jax.numpy as jnp
-    from ..core.index import walk_counts_ell
-    n, D = 16, 4
-    ell = jnp.full((n + 1, D), n, jnp.int32)
-    slack = jnp.zeros((n + 1,), jnp.int8)
-    return (lambda a, s: walk_counts_ell(a, jnp.int32(0), s, n=n, budget=k,
-                                         backend=backend),
-            (ell, slack))
-
-
 def _mk_expand_level(backend: str, k: int):
     import jax.numpy as jnp
     from ..core.enumerate import expand_level
@@ -188,8 +165,6 @@ MANIFEST: Tuple[HotFn, ...] = (
     HotFn("msbfs_set_dist", ("jnp",), _mk_msbfs_set_dist),
     HotFn("msbfs_dist_ell", ("jnp", "interpret"), _mk_msbfs_dist_ell),
     HotFn("msbfs_set_dist_ell", ("jnp", "interpret"), _mk_msbfs_set_dist_ell),
-    HotFn("walk_counts", ("jnp",), _mk_walk_counts),
-    HotFn("walk_counts_ell", ("jnp", "interpret"), _mk_walk_counts_ell),
     HotFn("expand_level", ("jnp", "interpret"), _mk_expand_level,
           leveled=False),
     HotFn("keyed_join", ("jnp", "interpret"), _mk_keyed_join, leveled=False),
@@ -203,8 +178,8 @@ MANIFEST: Tuple[HotFn, ...] = (
 # entry (see _OPS_COVERED) or listed here with a reason — silently
 # unaudited kernel math is an audit/coverage finding.
 AUDIT_EXEMPT_OPS: Dict[str, str] = {
-    "msbfs_expand": "single-hop building block superseded by the fused "
-                    "msbfs_step on the engine path; parity pinned by "
+    "msbfs_expand": "single-hop building block of msbfs_step (which the "
+                    "msbfs_*_ell entries reach); pinned against numpy by "
                     "tests/test_kernels.py",
     "path_overlap": "pairwise path-similarity op used by host-side "
                     "clustering tooling, not the per-level enumeration "
@@ -217,7 +192,8 @@ AUDIT_EXEMPT_OPS: Dict[str, str] = {
 }
 
 # ops each manifest entry's kernel arms route through (for coverage)
-_OPS_COVERED = {"msbfs_step", "ell_spmm", "rowwise_overlap", "path_member"}
+_OPS_COVERED = {"msbfs_step", "msbfs_count", "rowwise_overlap",
+                "path_member"}
 
 
 # ---------------------------------------------------------------------------

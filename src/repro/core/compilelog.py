@@ -14,7 +14,7 @@ compile reuse is observable in production stats and assertable in tests:
     recorder.retraces_since(snap)    # compiles of already-known kernels
 
 Mechanism: enabling flips the ``jax_log_compiles`` config flag, which
-makes jax emit one ``"Compiling <name> with global shapes..."`` log
+makes jax emit one ``"Compiling jit(<name>) with global shapes..."`` log
 record per actual trace-cache miss (cached executions emit nothing); a
 logging.Handler attached to the emitting jax loggers parses those records
 into per-kernel counters. Propagation of the captured loggers is disabled
@@ -41,12 +41,13 @@ from typing import Optional
 __all__ = ["CompileLog", "enable", "active"]
 
 # jax emits exactly one of these per XLA compilation when the
-# jax_log_compiles flag is on (jax._src.interpreters.pxla); the dispatch
-# logger's "Finished tracing/compilation ..." records deliberately do NOT
-# match, so each compile is counted once.
-_COMPILING_RE = re.compile(r"Compiling ([^\s]+) with global shapes")
+# jax_log_compiles flag is on, as "Compiling jit(<fn>) with global shapes
+# ..." on the pxla logger; the kernel name recorded is the bare function
+# name inside ``jit(...)``. The dispatch logger's "Finished tracing /
+# compilation ..." records do NOT match, so each compile is counted once;
+# it is captured only to keep those records off user output.
+_COMPILING_RE = re.compile(r"Compiling jit\(([^\s()]+)\) with global shapes")
 
-# every logger jax has used for the compile message across recent versions
 _JAX_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
 
 

@@ -42,7 +42,7 @@ from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
-from .graph import (DeviceGraph, Graph, _ragged_arange, pad_edge_list,
+from .graph import (DeviceGraph, Graph, ragged_arange, pad_edge_list,
                     pow2_ceil)
 
 __all__ = ["GraphDelta", "AppliedDelta", "apply_delta",
@@ -237,7 +237,7 @@ def _ell_rows(g: Graph, rows: np.ndarray, cap: int, reverse: bool,
     deg = (ip[rows + 1] - ip[rows]).astype(np.int64)
     idx = np.full((rows.size, cap), g.n, dtype=np.int32)
     r = np.repeat(np.arange(rows.size), deg)
-    c = _ragged_arange(deg)
+    c = ragged_arange(deg)
     idx[r, c] = ix[np.repeat(ip[rows], deg) + c]
     return idx, idx != g.n
 
@@ -343,7 +343,7 @@ def host_set_dist(g_old: Graph, applied: AppliedDelta, k_max: int,
             break
         deg = (ip[frontier + 1] - ip[frontier]).astype(np.int64)
         nbrs = np.unique(ix[np.repeat(ip[frontier], deg) +
-                            _ragged_arange(deg)].astype(np.int64))
+                            ragged_arange(deg)].astype(np.int64))
         frontier = nbrs[dist[nbrs] == INF]
         dist[frontier] = hop
     return dist

@@ -32,7 +32,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from .cache import node_signature
-from .graph import Graph
+from .graph import Graph, ragged_arange
 
 __all__ = ["PlanNode", "DirectionPlan", "detect_common_queries"]
 
@@ -107,28 +107,7 @@ def detect_common_queries(g: Graph, cluster: Sequence[int],
 
     k_max = max(b for _, b in halves.values()) if halves else 0
     M_Q = np.full(g.n, -1, dtype=np.int64)       # vertex -> nid
-    reach: dict[int, set[int]] = {}              # nid -> set of nids reachable via out_edges
-
-    def add_edge(child: int, parent: int) -> None:
-        """child's results spliced by parent; skip if it would close a cycle."""
-        if child == parent or parent in _reachable(child):
-            return
-        if child in nodes[parent].in_edges:
-            return
-        nodes[parent].in_edges.append(child)
-        nodes[child].out_edges.append(parent)
-
-    def _reachable(nid: int) -> set[int]:
-        # nodes reachable from nid following in_edges (its splice subtree)
-        seen, stack = set(), [nid]
-        while stack:
-            x = stack.pop()
-            for c in nodes[x].in_edges:
-                if c not in seen:
-                    seen.add(c)
-                    stack.append(c)
-        return seen
-
+    dag = _SpliceDAG(nodes)
     # arrivals for the current level: per node, vertex array
     arrivals: dict[int, np.ndarray] = {}
     n_shared = 0
@@ -161,9 +140,10 @@ def detect_common_queries(g: Graph, cluster: Sequence[int],
             if counts[ui] >= 2 and kappa >= min_shared_budget:
                 nid = len(nodes)
                 nodes.append(PlanNode(nid=nid, src=v, budget=kappa, query=None))
+                dag.append()
                 n_shared += 1
                 for m in members:
-                    add_edge(nid, int(m))     # members splice the shared node
+                    dag.add_edge(nid, int(m))  # members splice the shared node
                 cur = nid
             else:
                 cur = int(members[0])
@@ -176,7 +156,7 @@ def detect_common_queries(g: Graph, cluster: Sequence[int],
         # push to out-neighbors (vectorized CSR expansion over the level)
         deg = (indptr[uniq_v + 1] - indptr[uniq_v]).astype(np.int64)
         flat_owner = np.repeat(cur_of_vertex, deg)
-        offs = np.repeat(indptr[uniq_v], deg) + _ragged(deg)
+        offs = np.repeat(indptr[uniq_v], deg) + ragged_arange(deg)
         flat_nbr = indices[offs].astype(np.int64)
         ok = hop_ok[flat_nbr]
         flat_owner, flat_nbr = flat_owner[ok], flat_nbr[ok]
@@ -192,7 +172,8 @@ def detect_common_queries(g: Graph, cluster: Sequence[int],
         if e_child.size:
             pair = np.unique(e_child * (len(nodes) + 1) + e_parent)
             for p in pair:
-                add_edge(int(p // (len(nodes) + 1)), int(p % (len(nodes) + 1)))
+                dag.add_edge(int(p // (len(nodes) + 1)),
+                             int(p % (len(nodes) + 1)))
         # arrivals for next level
         a_owner = flat_owner[~has_mq]
         a_vert = flat_nbr[~has_mq]
@@ -204,23 +185,25 @@ def detect_common_queries(g: Graph, cluster: Sequence[int],
             for nid in np.unique(a_owner):
                 arrivals[int(nid)] = a_vert[cut[nid]:cut[nid + 1]]
 
-    # consumers: propagate (query, min_offset) down from parents to children
+    # consumers: propagate (query, min_offset) down from parents to
+    # children, keeping the smallest offset per query (loosest slack); one
+    # (len(cluster),) offset row per node, parents before children
     topo = _toposort(nodes)
-    for nid in reversed(topo):                   # parents before children
+    qpos = {qi: i for i, qi in enumerate(cluster)}
+    no_off = 1 << 30
+    best = np.full((len(nodes), len(cluster)), no_off, np.int32)
+    for nid in reversed(topo):
         node = nodes[nid]
+        row = best[nid]
         if node.query is not None:
             for qi in owners[nid]:
                 _, budget = halves[qi]
-                node.consumers.append((qi, budget - node.budget))
+                row[qpos[qi]] = min(row[qpos[qi]], budget - node.budget)
         for parent in node.out_edges:
-            for qi, off in nodes[parent].consumers:
-                node.consumers.append((qi, off + nodes[parent].budget - node.budget))
-        # dedupe, keep the smallest offset per query (loosest slack)
-        best: dict[int, int] = {}
-        for qi, off in node.consumers:
-            if qi not in best or off < best[qi]:
-                best[qi] = off
-        node.consumers = sorted(best.items())
+            np.minimum(row, best[parent] + (nodes[parent].budget
+                                            - node.budget), out=row)
+        has = np.flatnonzero(row < no_off // 2)
+        node.consumers = sorted((cluster[i], int(row[i])) for i in has)
 
     if endpoints is not None:
         direction = "b" if reverse else "f"
@@ -230,6 +213,67 @@ def detect_common_queries(g: Graph, cluster: Sequence[int],
 
     return DirectionPlan(nodes=nodes, topo=topo,
                          half_of_query=half_of_query, n_shared=n_shared)
+
+
+class _SpliceDAG:
+    """The splice edges of a plan, kept acyclic under insertion.
+
+    An edge ``parent -> child`` means parent splices child. ``add_edge``
+    drops an edge that would close a cycle, exactly as a full
+    reachability test would, but keeps a topological order of the nodes
+    (``ord[parent] < ord[child]`` on every edge) and searches only the
+    nodes between the two ends when a new edge goes against that order
+    (Pearce & Kelly's dynamic topological sort). An edge that agrees with
+    the order, the common case, costs O(1).
+    """
+
+    def __init__(self, nodes: list[PlanNode]):
+        self.nodes = nodes               # edgeless so far
+        self.ord = list(range(len(nodes)))
+        self.kids: list[set[int]] = [set() for _ in nodes]
+
+    def append(self) -> None:
+        """Order the node just appended to ``nodes`` (no edges yet) last."""
+        self.ord.append(len(self.ord))
+        self.kids.append(set())
+
+    def add_edge(self, child: int, parent: int) -> None:
+        """child's results spliced by parent; skip if it would close a cycle."""
+        if child == parent or child in self.kids[parent]:
+            return
+        ord_ = self.ord
+        lo, hi = ord_[child], ord_[parent]
+        if lo < hi:
+            # against the order: child must move after parent, which is
+            # impossible iff parent is already among child's descendants
+            fwd = self._search(child, hi, forward=True)
+            if fwd is None:
+                return
+            back = self._search(parent, lo, forward=False)
+            moved = sorted(back, key=ord_.__getitem__) + \
+                sorted(fwd, key=ord_.__getitem__)
+            for nid, o in zip(moved, sorted(ord_[x] for x in moved)):
+                ord_[nid] = o
+        self.kids[parent].add(child)
+        self.nodes[parent].in_edges.append(child)
+        self.nodes[child].out_edges.append(parent)
+
+    def _search(self, start: int, bound: int, *, forward: bool):
+        """Nodes reachable from ``start`` whose order lies inside the
+        window: descendants up to ``bound`` (forward; None if ``bound``'s
+        node is among them) or ancestors down to it."""
+        ord_, nodes = self.ord, self.nodes
+        seen, stack = {start}, [start]
+        while stack:
+            x = stack.pop()
+            for y in (nodes[x].in_edges if forward else nodes[x].out_edges):
+                o = ord_[y]
+                if forward and o == bound:
+                    return None
+                if y not in seen and (o < bound if forward else o > bound):
+                    seen.add(y)
+                    stack.append(y)
+        return seen
 
 
 def _toposort(nodes: list[PlanNode]) -> list[int]:
@@ -249,10 +293,3 @@ def _toposort(nodes: list[PlanNode]) -> list[int]:
         raise RuntimeError("sharing graph has a cycle (planner bug)")
     return out
 
-
-def _ragged(counts: np.ndarray) -> np.ndarray:
-    total = int(counts.sum())
-    if total == 0:
-        return np.zeros(0, np.int64)
-    offs = np.repeat(np.concatenate([[0], np.cumsum(counts)[:-1]]), counts)
-    return np.arange(total, dtype=np.int64) - offs

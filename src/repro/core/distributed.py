@@ -5,7 +5,7 @@ provided here and both degrading to the identity on a single device (a
 mesh of size 1 — or no mesh at all — runs exactly the single-device code):
 
   * **mesh-parallel index** -- the edge kernels (MS-BFS ``msbfs_dist`` /
-    ``msbfs_set_dist``, ``walk_counts``) are pure pjit programs over the
+    ``msbfs_set_dist``) are pure pjit programs over the
     dst-sorted edge lists, so sharding the edge axis over a named 1-D mesh
     ("cells") and letting GSPMD partition the gather + segment-reduce is a
     placement decision: :func:`shard_graph_edges` re-pads the PR-4
@@ -13,8 +13,7 @@ mesh of size 1 — or no mesh at all — runs exactly the single-device code):
     bucket is already divisible by any pow2 device count) and
     ``device_put``\\ s them under a ``NamedSharding``. Results are
     bit-equal to single-device: the boolean-semiring ``segment_max`` is
-    order-free and the walk-count ``segment_sum`` adds integer-valued
-    float32s (exact below 2**24).
+    order-free.
 
   * **cluster-parallel enumeration** -- sharing clusters are the natural
     data-parallel work unit (sharing graphs never cross clusters, per the
@@ -103,9 +102,7 @@ def shard_edges(esrc, edst, mesh, axes=None, *, n: int):
     Padding to a device multiple reuses the sentinel ``(n, n)`` pad from
     :func:`~repro.core.graph.pad_edge_list`: sentinel edges are dropped by
     every segment op and gather the zero sentinel row, so they are inert
-    in both the boolean BFS semiring and the walk-count ``segment_sum``.
-    (The earlier repeat-last-edge pad was only safe for ``segment_max`` —
-    a repeated real edge double-counts in ``walk_counts`` unless masked.)
+    in the boolean BFS semiring and in any counting ``segment_sum``.
     ``n`` is the vertex count the sentinel encodes. Sentinel ``n`` sorts
     after every real destination, so the dst-sorted invariant survives.
     """
@@ -170,11 +167,11 @@ def query_ball_cost(index, qi: int, dists: tuple) -> float:
     (:class:`repro.core.planner.CostRouter`). Deliberately cheap:
     callers need relative weight, not the exact DP bound.
     """
-    ds, dt = dists[0][:-1], dists[1][:-1]
+    ds, dt = dists
     _, _, k = index.queries[qi]
     a, b = midpoint_split(k)
-    ball = int((ds[:, index.src_col[qi]] <= a).sum()) \
-        + int((dt[:, index.tgt_col[qi]] <= b).sum())
+    ball = int((ds[:-1, index.src_col[qi]] <= a).sum()) \
+        + int((dt[:-1, index.tgt_col[qi]] <= b).sum())
     return float(k) * float(ball)
 
 
@@ -371,8 +368,6 @@ class ShardedExecutor:
         dists = eng._dists_host(index)
         costs = cluster_costs(index, clusters, dists=dists)
         assign, loads = plan_clusters(costs, len(reps))
-        for rep in reps[1:]:
-            rep._host_dists = eng._host_dists   # share the memo, read-only
 
         outs: list[dict] = [{} for _ in reps]
         cstats_all: list[list[dict]] = [[] for _ in reps]
@@ -394,9 +389,12 @@ class ShardedExecutor:
                     # always a concrete device (the no-mesh executor
                     # never fans out)
                     with jax.default_device(dev):
+                        # the replica's slack vectors come from its own
+                        # copy of the distance tables
+                        local = index.on_device(dev)
                         for ci in assign[ri]:
                             out, cst = cluster_fn(rep, ci)(
-                                queries, index, plus, min_sb, clusters[ci])
+                                queries, local, plus, min_sb, clusters[ci])
                             outs[ri].update(out)
                             cstats_all[ri].append(cst)
                 walls[ri] = sr.duration

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
+import weakref
 from typing import Callable, Optional, Sequence
 
 import jax.numpy as jnp
@@ -31,13 +32,14 @@ from .cache import SharedPathCache
 from .delta import (AppliedDelta, GraphDelta, apply_delta as _merge_delta,
                     host_set_dist, pow2_ceil as _pow2, update_device_graph)
 from .graph import DeviceGraph, Graph
-from .index import QueryIndex, build_index, walk_counts, walk_counts_ell
+from .index import (QueryIndex, build_index, column_reach, slack_vector,
+                    walk_counts)
 from .msbfs import (K_MAX_INT8, edge_span, msbfs_set_dist,
                     msbfs_set_dist_ell)
 from ..kernels.registry import resolve_backend
 from .pathset import PathSet, concat, empty, singleton
 from .enumerate import (count_ending_at, expand_level, extract_rows,
-                        prune_table, select_ending_at)
+                        select_ending_at, splice_hits, splice_table)
 from .join import cross_join, keyed_join, keyed_join_count, sort_by_last
 from .planner import CostRouter, Route, RouterConfig
 from .query import (BatchReport, Output, PathQuery, PathsStore, Planner,
@@ -164,7 +166,7 @@ class BatchPathEngine:
             n_dev = int(np.prod(list(mesh.shape.values())))
             self.dg = DeviceGraph.build(
                 graph, edge_cap=distributed.edge_bucket_for(graph.m, n_dev))
-        self._host_dists: Optional[tuple] = None   # (index, (dist_s, dist_t))
+        self._host_dists: Optional[tuple] = None   # (weakref(dist_s), dists)
         # plan -> place -> gather layer; identity on a single device (the
         # executor IS the cluster-execution loop for every engine)
         self.executor: Optional[distributed.ShardedExecutor] = \
@@ -367,29 +369,26 @@ class BatchPathEngine:
         return dists
 
     def _dists_host(self, index: QueryIndex):
-        # memoized per index OBJECT: keep a strong reference so a freed
-        # index's id can never be reused to serve stale distances
-        if self._host_dists is None or self._host_dists[0] is not index:
-            self._host_dists = (index, (np.asarray(index.dist_s),
-                                        np.asarray(index.dist_t)))
+        # memoized per distance TABLE (subsets of one index share it),
+        # held by a weak reference: a freed table's id can never serve
+        # stale distances, and the memo does not keep the device tables
+        # alive after their batch. Transposed on the device and viewed
+        # back on the host, so each (n+1, S) matrix is column-major: one
+        # query's column is a contiguous read, not n+1 reads S bytes apart.
+        if (self._host_dists is None
+                or self._host_dists[0]() is not index.dist_s):
+            self._host_dists = (weakref.ref(index.dist_s),
+                                (np.asarray(index.dist_s.T).T,
+                                 np.asarray(index.dist_t.T).T))
         return self._host_dists[1]
-
-    @staticmethod
-    def _slack_np(dist_cols: np.ndarray, ks: np.ndarray,
-                  offs: np.ndarray, INF: int):
-        d = dist_cols.astype(np.int32)
-        val = ks[None, :] - offs[None, :] - d
-        val = np.where(d >= INF, -1, val)
-        out = np.clip(val.max(axis=1), -1, 127).astype(np.int8)
-        out[-1] = -1
-        return jnp.asarray(out)
 
     # ------------------------------------------------------------------
     # public API
     # ------------------------------------------------------------------
     def run(self, queries: Sequence[QueryLike],
             planner: Planner | str = Planner.BATCH,
-            clusters: Optional[list[list[int]]] = None) -> BatchReport:
+            clusters: Optional[list[list[int]]] = None,
+            index: Optional[QueryIndex] = None) -> BatchReport:
         """Execute a batch of :class:`PathQuery` (tuples are coerced).
 
         planner : execution strategy (:class:`Planner` or its string value).
@@ -398,6 +397,10 @@ class BatchPathEngine:
         clusters with a cache-aware bias — keeps its grouping instead of
         this method re-running similarity + clustering over the same
         queries.
+        index : optional prebuilt :class:`~repro.core.index.QueryIndex`
+        over exactly these queries (the streaming server's, already
+        built for its similarity); None builds one. Ignored by PATHENUM,
+        which indexes each query alone.
 
         With ``EngineConfig.log_compiles`` the report stats carry this
         run's compile-telemetry window: ``n_compiles`` (trace-cache
@@ -405,15 +408,16 @@ class BatchPathEngine:
         — zero on a shape-stable serving path) and ``compiled_kernels``.
         """
         if self.compile_log is None:
-            return self._run_impl(queries, planner, clusters)
+            return self._run_impl(queries, planner, clusters, index)
         snap = self.compile_log.snapshot()
-        report = self._run_impl(queries, planner, clusters)
+        report = self._run_impl(queries, planner, clusters, index)
         self.compile_log.annotate(report.stats, snap)
         return report
 
     def _run_impl(self, queries: Sequence[QueryLike],
                   planner: Planner | str,
-                  clusters: Optional[list[list[int]]]) -> BatchReport:
+                  clusters: Optional[list[list[int]]],
+                  index: Optional[QueryIndex]) -> BatchReport:
         qs = tuple(PathQuery.coerce(q).check_bounds(self.g.n)
                    for q in queries)
         planner = Planner.coerce(planner)
@@ -429,12 +433,15 @@ class BatchPathEngine:
             if planner is Planner.PATHENUM:
                 report = self._run_pathenum(qs, stats)
             else:
-                with self.obs.span("index.build",
-                                   n_queries=len(qs)) as sidx:
-                    index = build_index(self._kernel_dg(),
-                                        [q.key for q in qs],
-                                        self.cfg.edge_chunk,
-                                        backend=self._kb)
+                keys = tuple(q.key for q in qs)
+                with self.obs.span("index.build", n_queries=len(qs),
+                                   given=index is not None) as sidx:
+                    if index is None:
+                        index = build_index(self._kernel_dg(), keys,
+                                            self.cfg.edge_chunk,
+                                            backend=self._kb)
+                    elif index.queries != keys:
+                        raise ValueError("index was built for other queries")
                     index.dist_s.block_until_ready()
                 stats["t_build_index"] = sidx.duration
                 if planner is Planner.AUTO:
@@ -731,8 +738,8 @@ class BatchPathEngine:
 
         This is the executor's unit of placement: it touches only
         replica-local state (``self.dg``, ``self.cache``) plus read-only
-        shared inputs (host graph, index host-dist memo), so distinct
-        clusters run concurrently on distinct replicas.
+        inputs (host graph, the index on the replica's device), so
+        distinct clusters run concurrently on distinct replicas.
         """
         cstats = {"n_psi_nodes": 0, "n_materialized": 0,
                   "n_cache_hits": 0, "n_cache_misses": 0,
@@ -900,12 +907,17 @@ class BatchPathEngine:
         ell_idx, _ = self.dg.direction(reverse)
         width = budget + 1
         n = self.dg.n
-        splice_np = np.full(n + 1, -1, np.int8)
-        for (csrc, cb, _) in children:
-            splice_np[csrc] = cb
+        # children's roots and budgets, padded to a pow2 bucket with the
+        # sentinel row n (its splice budget stays -1)
+        n_pad = _pow2(max(len(children), 1))
+        csrcs = np.full(n_pad, n, np.int32)
+        cbs = np.full(n_pad, -1, np.int8)
+        for i, (csrc, cb, _) in enumerate(children):
+            csrcs[i], cbs[i] = csrc, cb
+        csrcs = jnp.asarray(csrcs)
         # slack + splice stacked once per node; every expand level then
         # pays a single fused prune gather (see enumerate.prune_table)
-        prune_tbl = prune_table(slack, jnp.asarray(splice_np))
+        prune_tbl = splice_table(slack, csrcs, jnp.asarray(cbs))
         stop = jnp.int32(stop_vertex)
 
         pools: list[list[PathSet]] = [[] for _ in range(budget + 1)]
@@ -929,7 +941,12 @@ class BatchPathEngine:
                 overflow = bool(out.frontier.overflow)
             if overflow:
                 return None
-            for (csrc, cb, clevels) in children:
+            hit = (np.asarray(splice_hits(out.nbrs, out.splice_hit, csrcs,
+                                           n=n))
+                   if children else ())
+            for (csrc, cb, clevels), any_hit in zip(children, hit):
+                if not any_hit:
+                    continue
                 with obs.span("join.splice", level=lvl):
                     rmask = (out.splice_hit & (out.nbrs == csrc)).any(axis=1)
                     prefixes = extract_rows(frontier.verts, rmask,
@@ -1104,9 +1121,8 @@ class BatchPathEngine:
         # "+" variants: pick the split minimizing estimated search cost
         fs = self._dedicated_slack(index, qi, forward=True)
         bs = self._dedicated_slack(index, qi, forward=False)
-        kdg = self._kernel_dg()
-        cf = self._walk_counts(kdg, False, s, fs, k - 1)
-        cb = self._walk_counts(kdg, True, t, bs, k - 1)
+        cf = self._walk_counts(False, s, fs, k - 1)
+        cb = self._walk_counts(True, t, bs, k - 1)
         best, best_cost = a, None
         for cand in range(1, k):
             cost = cf[:cand + 1].sum() + cb[:k - cand + 1].sum()
@@ -1115,34 +1131,28 @@ class BatchPathEngine:
         return best, k - best
 
     def _dedicated_slack(self, index: QueryIndex, qi: int, forward: bool):
-        s, t, k = index.queries[qi]
-        ds, dt = self._dists_host(index)
-        col = (dt[:, index.tgt_col[qi]] if forward
-               else ds[:, index.src_col[qi]])[:, None]
-        return self._slack_np(col, np.array([k], np.int32),
-                              np.array([0], np.int32), index.INF)
+        return self._node_slack(index, [(qi, 0)], forward)
 
     def _node_slack(self, index: QueryIndex, consumers, forward: bool):
+        """(n+1,) int8 device slack of a search whose consumers are
+        (query, offset) pairs, computed where the index lives."""
         qs = [qi for qi, _ in consumers]
-        offs = np.array([off for _, off in consumers], np.int32)
-        ks = np.array([index.queries[qi][2] for qi in qs], np.int32)
-        ds, dt = self._dists_host(index)
-        cols = dt[:, index.tgt_col[qs]] if forward else ds[:, index.src_col[qs]]
-        return self._slack_np(cols, ks, offs, index.INF)
+        reach = (np.array([index.queries[qi][2] for qi in qs], np.int32)
+                 - np.array([off for _, off in consumers], np.int32))
+        dist, cols = index.table(forward)
+        return slack_vector(dist, jnp.asarray(
+            column_reach(dist.shape[1], cols[qs], reach)), index.INF)
 
     def _hop_ok(self, index: QueryIndex, cluster, forward: bool) -> np.ndarray:
+        """(n,) bool on the host: vertices within the cluster's largest k
+        of some cluster endpoint (the detection's loose filter)."""
         k_max = max(index.queries[qi][2] for qi in cluster)
-        # host-dist memo instead of per-cluster device transfers: replica
-        # threads share the (read-only) memo, so no gather contention
-        ds, dt = self._dists_host(index)
-        if forward:
-            cols = dt[:-1, index.tgt_col[list(cluster)]]
-        else:
-            cols = ds[:-1, index.src_col[list(cluster)]]
-        return (cols.min(axis=1) <= k_max)
+        hop = self._node_slack(index, [(qi, index.queries[qi][2] - k_max)
+                                       for qi in cluster], forward)
+        return np.asarray(hop)[:-1] >= 0
 
     def _kernel_dg(self) -> DeviceGraph:
-        """Edge lists the index/walk kernels sweep: the GSPMD-sharded
+        """Edge lists the index kernels sweep: the GSPMD-sharded
         mesh view on a primary engine with an executor, the local device
         view on replicas (``executor is None``) and plain engines. While
         a cluster fan-out is in flight the primary (= replica 0) also
@@ -1153,37 +1163,19 @@ class BatchPathEngine:
             return self.executor.index_dg
         return self.dg
 
-    def _m_valid(self, dg: Optional[DeviceGraph] = None) -> int:
-        """Chunk-rounded valid-edge span of the (sentinel-padded) device
-        edge lists — the static ``m_valid`` every edge kernel receives."""
-        dg = self.dg if dg is None else dg
-        return edge_span(dg.m, self.cfg.edge_chunk, dg.m_cap)
-
-    def _walk_counts(self, kdg: DeviceGraph, reverse: bool, source, slack,
+    def _walk_counts(self, reverse: bool, source: int, slack,
                      budget: int) -> np.ndarray:
-        """Per-level walk-count DP through the configured kernel backend:
-        one ELL gather-reduce dispatch per level (``walk_counts_ell``) on
-        the kernel route, the chunked edge-list segment_sum on jnp.
-        Totals are integer-valued f32, identical below 2**24."""
-        if self.kernel_backend.uses_kernel:
-            # in-neighbor table of the swept direction: forward counts on G
-            # relax over r_ell (in-nbrs of G), reverse counts over ell
-            ell = kdg.ell_idx if reverse else kdg.r_ell_idx
-            return np.asarray(walk_counts_ell(ell, source, slack, n=kdg.n,
-                                              budget=budget,
-                                              backend=self._kb))
-        esrc = kdg.r_esrc if reverse else kdg.esrc
-        edst = kdg.r_edst if reverse else kdg.edst
-        return np.asarray(walk_counts(esrc, edst, source, slack, n=kdg.n,
-                                      budget=budget,
-                                      edge_chunk=self.cfg.edge_chunk,
-                                      m_valid=self._m_valid(kdg)))
+        """Per-level pruned-walk counts of one search (``index.walk_counts``
+        over the host CSR) under the node's device slack, fetched once."""
+        g = self.g
+        indptr, indices = ((g.r_indptr, g.r_indices) if reverse
+                           else (g.indptr, g.indices))
+        return walk_counts(indptr, indices, source, np.asarray(slack), budget)
 
     def _plan_caps(self, reverse: bool, source: int, budget: int, slack):
         if not self.cfg.plan_caps:
             return [self.cfg.min_cap] * (budget + 1)
-        kdg = self._kernel_dg()
-        tot = self._walk_counts(kdg, reverse, source, slack, budget)
+        tot = self._walk_counts(reverse, source, slack, budget)
         caps = [_bucket(min(int(min(t, 2**31)), self.cfg.max_cap),
                         self.cfg.min_cap) for t in tot]
         return caps

@@ -21,8 +21,9 @@ import jax.numpy as jnp
 
 from .pathset import PathSet, compact_rows
 
-__all__ = ["ExpandOut", "expand_level", "prune_table", "extract_rows",
-           "select_ending_at", "count_ending_at"]
+__all__ = ["ExpandOut", "expand_level", "prune_table", "splice_table",
+           "splice_hits", "extract_rows", "select_ending_at",
+           "count_ending_at"]
 
 
 class ExpandOut(NamedTuple):
@@ -38,6 +39,28 @@ def prune_table(slack: jax.Array, splice_budget: jax.Array) -> jax.Array:
     node run (both vectors are fixed for a node), so every level pays a
     single fused gather instead of one gather per vector."""
     return jnp.stack([slack, splice_budget], axis=1)
+
+
+@jax.jit
+def splice_table(slack: jax.Array, roots: jax.Array,
+                 budgets: jax.Array) -> jax.Array:
+    """:func:`prune_table` with the splice column scattered on the device:
+    ``roots`` (C,) int32 splice-child root vertices, ``budgets`` (C,) int8
+    their budgets; pad entries point at the sentinel row n with -1."""
+    splice = jnp.full(slack.shape, -1, jnp.int8).at[roots].set(budgets)
+    return prune_table(slack, splice)
+
+
+@partial(jax.jit, static_argnames=("n",))
+def splice_hits(nbrs: jax.Array, splice_hit: jax.Array, roots: jax.Array,
+                *, n: int) -> jax.Array:
+    """(C,) bool: whether any splice trigger of an :class:`ExpandOut`
+    landed on each root in ``roots`` — one dispatch per level instead of
+    one row mask per splice child. Pad entries of ``nbrs`` and ``roots``
+    are the sentinel n, where no trigger lands."""
+    landed = jnp.zeros((n + 1,), bool).at[nbrs.reshape(-1)].max(
+        splice_hit.reshape(-1))
+    return landed[roots]
 
 
 @partial(jax.jit, static_argnames=("level", "budget", "out_cap", "backend"))

@@ -19,8 +19,8 @@ re-uses warm XLA compiles instead of retracing on each new ``(m,)``.
 Edge lists are padded with **sentinel edges** ``(n, n)``: ``edst = n`` is
 out of segment range, so ``segment_max`` / ``segment_sum`` drop the
 message, and ``esrc = n`` gathers the all-zero sentinel row that every
-frontier table carries — a sentinel edge is inert in both the boolean BFS
-semiring and the walk-count DP. ELL capacities are bucketed the same way,
+frontier table carries — a sentinel edge is inert in the boolean BFS
+semiring. ELL capacities are bucketed the same way,
 so a touched row growing within its bucket never changes the ``(n, cap)``
 kernel shapes.
 """
@@ -98,9 +98,7 @@ class Graph:
             keep = src != dst  # drop self loops: never on a simple path twice
             src, dst = src[keep], dst[keep]
         if dedup and src.size:
-            key = src * n + dst
-            _, uniq = np.unique(key, return_index=True)
-            src, dst = src[uniq], dst[uniq]
+            src, dst = np.divmod(np.unique(src * n + dst), n)
         indptr, indices = _csr(n, src, dst)
         r_indptr, r_indices = _csr(n, dst, src)
         return Graph(n=n, indptr=indptr, indices=indices,
@@ -147,14 +145,14 @@ class Graph:
         # vectorized fill of the first `cap` neighbors per row
         take = np.minimum(deg, cap)
         rows = np.repeat(np.arange(self.n), take)
-        cols = _ragged_arange(take)
+        cols = ragged_arange(take)
         flat = np.repeat(ip[:-1], take) + cols
         idx[rows, cols] = ix[flat]
         mask = idx != self.n
         # spill: neighbors beyond cap
         extra = deg - take
         s_rows = np.repeat(np.arange(self.n, dtype=np.int32), extra)
-        s_cols = _ragged_arange(extra) + np.repeat(take, extra)
+        s_cols = ragged_arange(extra) + np.repeat(take, extra)
         s_flat = np.repeat(ip[:-1], extra) + s_cols
         return EllView(idx=idx, mask=mask,
                        spill_src=s_rows, spill_dst=ix[s_flat].astype(np.int32),
@@ -182,15 +180,16 @@ class Graph:
 
 
 def _csr(n: int, src: np.ndarray, dst: np.ndarray):
-    order = np.lexsort((dst, src))
-    src, dst = src[order], dst[order]
+    # one sort of the (src, dst) pair packed into an int64 key; equal keys
+    # are equal pairs, so the order among them cannot show
+    src, dst = np.divmod(np.sort(src.astype(np.int64) * n + dst), n)
     counts = np.bincount(src, minlength=n).astype(np.int64)
     indptr = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(counts, out=indptr[1:])
     return indptr, dst.astype(np.int32)
 
 
-def _ragged_arange(counts: np.ndarray) -> np.ndarray:
+def ragged_arange(counts: np.ndarray) -> np.ndarray:
     """[0..c0), [0..c1), ... concatenated."""
     counts = counts.astype(np.int64)
     total = int(counts.sum())

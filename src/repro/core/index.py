@@ -7,28 +7,29 @@ engine-internal derived products:
   * slack vectors -- per-query / per-shared-node prune thresholds
       slack[v] = max over consumers (k_q - offset_q - dist(v, endpoint_q))
     A frontier vertex v at depth d survives iff d <= slack[v]
-    (equivalently Lemma 3.1's  |p| + dist(v, t) <= k).
+    (equivalently Lemma 3.1's  |p| + dist(v, t) <= k). One device pass
+    over a distance table (``slack_vector``).
 
   * walk-count DP -- c_{l+1}[v] = sum_{(u,v)} c_l[u] * [slack[v] >= l+1]
     an upper bound on per-level path counts, used to plan static buffer
     capacities and to pick the forward/backward split (the "+" variants'
-    cost-based search order, after PathEnum [15]).
+    cost-based search order, after PathEnum [15]). Host-side over the
+    CSR, touching only the vertices the walks reach (``walk_counts``).
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .graph import DeviceGraph
+from .graph import DeviceGraph, ragged_arange
 from .msbfs import edge_span, msbfs_dist, msbfs_dist_ell, INF_FOR
 
-__all__ = ["QueryIndex", "build_index", "walk_counts", "walk_counts_ell",
-           "slack_from_dists"]
+__all__ = ["QueryIndex", "build_index", "walk_counts", "slack_vector",
+           "column_reach"]
 
 Query = tuple[int, int, int]  # (s, t, k)
 
@@ -45,38 +46,53 @@ class QueryIndex:
     dist_t: jax.Array         # (n+1, Tu) int8 -- dist_{G_r}(t, v) = dist_G(v, t)
     INF: int
 
-    def fwd_slack(self, qi: int) -> jax.Array:
-        """(n+1,) int8 slack for the forward search of query qi."""
-        s, t, k = self.queries[qi]
-        return slack_from_dists(self.dist_t[:, self.tgt_col[qi]][:, None],
-                                np.array([k]), np.array([0]), self.INF)
+    def subset(self, qis: Sequence[int]) -> "QueryIndex":
+        """The index of queries ``qis`` (positions in this one), sharing
+        its distance tables: a column serves every query with that
+        endpoint, and distances up to each query's k are the same."""
+        qis = np.asarray(qis, np.int64)
+        return dataclasses.replace(
+            self, queries=tuple(self.queries[i] for i in qis),
+            src_col=self.src_col[qis], tgt_col=self.tgt_col[qis])
 
-    def bwd_slack(self, qi: int) -> jax.Array:
-        s, t, k = self.queries[qi]
-        return slack_from_dists(self.dist_s[:, self.src_col[qi]][:, None],
-                                np.array([k]), np.array([0]), self.INF)
+    def on_device(self, device) -> "QueryIndex":
+        """This index with its distance tables copied to ``device``."""
+        return dataclasses.replace(
+            self, dist_s=jax.device_put(self.dist_s, device),
+            dist_t=jax.device_put(self.dist_t, device))
 
-    def gamma_sizes(self, hops: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """|Γ(q)|, |Γ_r(q)| for each query (vertices within q.k hops)."""
-        ds = np.asarray(self.dist_s)[:-1]  # (n, Su)
-        dt = np.asarray(self.dist_t)[:-1]
-        gs = (ds[:, self.src_col] <= hops[None, :]).sum(0)
-        gr = (dt[:, self.tgt_col] <= hops[None, :]).sum(0)
-        return gs, gr
+    def table(self, forward: bool) -> tuple[jax.Array, np.ndarray]:
+        """The distance table a search in that direction prunes with and
+        each query's column in it: dist_t / tgt_col for forward searches
+        (distance to the target), dist_s / src_col for backward ones."""
+        return ((self.dist_t, self.tgt_col) if forward
+                else (self.dist_s, self.src_col))
 
 
-def slack_from_dists(dist_cols: jax.Array, ks: np.ndarray, offsets: np.ndarray,
-                     INF: int) -> jax.Array:
-    """slack[v] = max_c (ks[c] - offsets[c] - dist_cols[v, c]); INF dist -> -1.
+@jax.jit
+def slack_vector(dist: jax.Array, reach: jax.Array, inf) -> jax.Array:
+    """(n+1,) int8 prune thresholds from one (n+1, C) int8 distance table.
 
-    dist_cols: (n+1, C) int8; returns (n+1,) int8 (row n forced to -1).
+    slack[v] = max over columns c of reach[c] - dist[v, c], floored at -1,
+    with a distance >= ``inf`` contributing -1; row n is -1. ``reach[c]``
+    is the largest k_q - offset_q over the consumers whose endpoint is
+    column c, and -1 for a column no consumer uses (which then cannot
+    raise any entry above -1). One dense pass over the table on the
+    device: no column gather and no host copy.
     """
-    d = dist_cols.astype(jnp.int32)
-    val = ks[None, :].astype(np.int32) - offsets[None, :].astype(np.int32) - d
-    val = jnp.where(d >= INF, -1, val)
-    out = jnp.max(val, axis=1)
-    out = jnp.clip(out, -1, 127).astype(jnp.int8)
+    d = dist.astype(jnp.int32)
+    val = jnp.where(d >= inf, -1, reach[None, :] - d)
+    out = jnp.maximum(jnp.max(val, axis=1), -1).astype(jnp.int8)
     return out.at[-1].set(-1)
+
+
+def column_reach(n_cols: int, cols: np.ndarray, reach: np.ndarray) -> np.ndarray:
+    """The ``reach`` argument of :func:`slack_vector`: the largest of
+    ``reach`` per column in ``cols`` (several consumers may share an
+    endpoint), -1 elsewhere."""
+    out = np.full(n_cols, -1, np.int32)
+    np.maximum.at(out, np.asarray(cols, np.int64), np.asarray(reach, np.int32))
+    return out
 
 
 def build_index(dg: DeviceGraph, queries: Sequence[Query],
@@ -121,65 +137,31 @@ def build_index(dg: DeviceGraph, queries: Sequence[Query],
                       dist_s=dist_s, dist_t=dist_t, INF=INF_FOR(k_max))
 
 
-@partial(jax.jit, static_argnames=("n", "budget", "edge_chunk", "m_valid"))
-def walk_counts(esrc: jax.Array, edst: jax.Array, source, slack: jax.Array,
-                *, n: int, budget: int, edge_chunk: int = 1 << 22,
-                m_valid: Optional[int] = None) -> jax.Array:
-    """Per-level pruned-walk counts: upper bound on enumeration frontier sizes.
+def walk_counts(indptr: np.ndarray, indices: np.ndarray, source: int,
+                slack: np.ndarray, budget: int) -> np.ndarray:
+    """Per-level pruned-walk counts from ``source`` over a host CSR: an
+    upper bound on the enumeration's frontier sizes.
 
-    Returns (budget+1,) float32 totals (level 0 == 1). Uses float to avoid
-    overflow on explosive workloads; the planner clamps anyway.
-
-    The count vector carries the zero sentinel row ``n``, so a sentinel
-    edge ``(n, n)`` gathers 0.0 and its segment id is dropped — padded and
-    exact edge lists produce bit-equal totals. ``m_valid`` is the
-    chunk-rounded span from :func:`~repro.core.msbfs.edge_span` (static;
-    callers must pre-round).
+    A walk may step onto v at level l iff ``slack[v] >= l`` (``slack`` is
+    the host copy of the search's (n+1,) int8 prune vector). Only the
+    vertices the pruned walks reach are touched, a few thousand for a
+    half-query at k=6, never all n per level. Returns (budget+1,) float64
+    totals, level 0 == 1.
     """
-    m = esrc.shape[0]
-    m_used = m if m_valid is None else min(int(m_valid), m)
-    c = jnp.zeros((n + 1,), jnp.float32).at[source].set(1.0)
-    totals = [jnp.float32(1.0)]
+    verts = np.array([source], np.int64)
+    cnt = np.ones(1, np.float64)
+    totals = [1.0]
     for lvl in range(1, budget + 1):
-        nxt = jnp.zeros((n,), jnp.float32)
-        for lo in range(0, m_used, edge_chunk):
-            hi = min(lo + edge_chunk, m)
-            # whole-list sweeps skip the slice so sharded edge lists stay
-            # shard-local (see msbfs_hop); sums are integer-valued f32,
-            # exact below 2**24 regardless of partitioned reduce order
-            es, ed = (esrc, edst) if lo == 0 and hi == m \
-                else (esrc[lo:hi], edst[lo:hi])
-            msgs = c[es]
-            nxt = nxt + jax.ops.segment_sum(msgs, ed, num_segments=n,
-                                            indices_are_sorted=True)
-        nxt = nxt * (slack[:-1] >= lvl)
-        c = jnp.concatenate([nxt, jnp.zeros((1,), jnp.float32)])
-        totals.append(jnp.sum(nxt))
-    return jnp.stack(totals)
+        starts = indptr[verts]
+        deg = (indptr[verts + 1] - starts).astype(np.int64)
+        nbr = indices[np.repeat(starts, deg) + ragged_arange(deg)]
+        w = np.repeat(cnt, deg)
+        keep = slack[nbr] >= lvl
+        verts, inv = np.unique(nbr[keep], return_inverse=True)
+        cnt = np.bincount(inv, weights=w[keep], minlength=verts.size)
+        totals.append(float(cnt.sum()))
+        if verts.size == 0:
+            totals += [0.0] * (budget - lvl)
+            break
+    return np.array(totals)
 
-
-@partial(jax.jit, static_argnames=("n", "budget", "backend"))
-def walk_counts_ell(ell_in_idx: jax.Array, source, slack: jax.Array,
-                    *, n: int, budget: int,
-                    backend: str = "interpret") -> jax.Array:
-    """Kernel twin of :func:`walk_counts`: the per-level DP step is one
-    ELL gather-reduce dispatch (kernels/ell_spmm) instead of the chunked
-    edge-list segment_sum.
-
-    ell_in_idx: (n+1, D) padded ELL *in*-neighbor table (forward counts on
-    G take ``dg.r_ell_idx``, reverse counts take ``dg.ell_idx`` — same
-    convention as :func:`~repro.core.msbfs.msbfs_dist_ell`). Totals are
-    integer-valued f32, exact (= bit-equal to the segment path) below
-    2**24 regardless of reduce order.
-    """
-    from ..kernels.ell_spmm.ops import ell_aggregate
-
-    idx = ell_in_idx[:n]                       # (n, D), pad = n
-    c = jnp.zeros((n,), jnp.float32).at[source].set(1.0)
-    totals = [jnp.float32(1.0)]
-    for lvl in range(1, budget + 1):
-        nxt = ell_aggregate(idx, c[:, None], op="sum", backend=backend)[:, 0]
-        nxt = nxt * (slack[:-1] >= lvl)
-        c = nxt
-        totals.append(jnp.sum(nxt))
-    return jnp.stack(totals)

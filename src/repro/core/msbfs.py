@@ -7,10 +7,13 @@ edge-gather + ``segment_max`` (max == OR on {0,1}), i.e. a sparse-matrix ×
 dense-frontier product in the boolean semiring — MXU/VPU-friendly and
 shardable.
 
-Two backends:
-  * ``jnp``    -- reference path used everywhere (chunked edge gathers).
-  * ``pallas`` -- bit-packed ELL OR-gather kernel (kernels/msbfs_expand),
-                  validated against this reference in interpret mode.
+Two sweeps:
+  * ``msbfs_dist``     -- int8 frontier, chunked edge-list gathers (the
+                          ``jnp`` kernel backend).
+  * ``msbfs_dist_ell`` -- bit-packed frontier, OR-gather over the padded
+                          ELL table (kernels/msbfs_expand; the ``pallas``
+                          and ``interpret`` backends), bit-equal to the
+                          edge-list sweep.
 
 Distances are int8 (k_max <= 120); unreached = INF = k_max + 1.
 
@@ -166,14 +169,14 @@ def msbfs_dist(esrc: jax.Array, edst: jax.Array, sources: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# fused-kernel twins: bit-packed sweeps over the padded ELL in-neighbor
-# table (kernels/msbfs_expand). One level = ONE device dispatch (expand +
-# visited dedup + distance write fused in msbfs_step) instead of the
-# segment-op path's gather / segment_max / mask-mul / where chain. The ELL
-# tables are already sentinel-padded to stable pow2 capacities
-# (DeviceGraph.build), so these sweeps inherit the zero-warm-retrace
-# guarantee without edge chunking: m_valid has no analogue here because
-# sentinel rows gather the all-zero frontier row n and contribute nothing.
+# packed twins: bit-packed sweeps over the padded ELL in-neighbor table
+# (kernels/msbfs_expand msbfs_step: expand + visited dedup per level),
+# 32 sources per uint32 word instead of one int8 byte each on the
+# segment-op path. The ELL tables are already sentinel-padded
+# to stable pow2 capacities (DeviceGraph.build), so these sweeps inherit
+# the zero-warm-retrace guarantee without edge chunking: m_valid has no
+# analogue here because sentinel rows gather the all-zero frontier row n
+# and contribute nothing.
 #
 # Direction convention (matches msbfs_dist's edge-list arguments):
 # relaxation is next[v] = OR over in-neighbors u of v, so forward
@@ -181,10 +184,39 @@ def msbfs_dist(esrc: jax.Array, edst: jax.Array, sources: jax.Array,
 # G_r == in-neighbors in G) and distances on G_r take dg.ell_idx.
 # ---------------------------------------------------------------------------
 
+def _packed_sweep(idx: jax.Array, frontier: jax.Array, S: int, k_max: int,
+                  backend: str) -> jax.Array:
+    """Levels 1..k_max from the packed level-0 ``frontier`` (n+1, W),
+    whose row n stays 0. Returns (n+1, S) int8 distances.
+
+    A vertex first reached at hop d has its bit clear in the visited sets
+    of levels 0..d-1 and set from d on, so its distance is the number of
+    levels (0..k_max) at which the bit is clear; never reached gives
+    k_max + 1 = INF. The running count is kept source-major (32*W, n),
+    so unpacking a word is a stack of sublane rows (kernels/msbfs_expand
+    ``msbfs_count``), and transposed once at the end.
+    """
+    from ..kernels.msbfs_expand.ops import msbfs_step, unreached_count
+
+    n, W = idx.shape[0], frontier.shape[1]
+    visited = frontier[:n]
+    count = unreached_count(visited, jnp.zeros((32 * W, n), jnp.int8),
+                            backend)
+    zero = jnp.zeros((1, W), jnp.uint32)
+    for hop in range(1, k_max + 1):
+        with jax.named_scope(f"msbfs.hop{hop}"):
+            new, visited = msbfs_step(idx, frontier, visited,
+                                      backend=backend)
+            frontier = jnp.concatenate([new, zero], axis=0)
+            count = unreached_count(visited, count, backend)
+    inf = jnp.full((1, S), INF_FOR(k_max), jnp.int8)
+    return jnp.concatenate([count[:S].T, inf], axis=0)
+
+
 @partial(jax.jit, static_argnames=("n", "k_max", "backend"))
 def msbfs_dist_ell(ell_in_idx: jax.Array, sources: jax.Array,
                    *, n: int, k_max: int, backend: str = "jnp") -> jax.Array:
-    """Fused-kernel twin of :func:`msbfs_dist`.
+    """Packed ELL twin of :func:`msbfs_dist`.
 
     ell_in_idx : (n+1, D) int32 padded ELL *in*-neighbor table (pad = n;
                  row n is the sentinel row, never expanded).
@@ -195,56 +227,32 @@ def msbfs_dist_ell(ell_in_idx: jax.Array, sources: jax.Array,
     Returns (n+1, S) int8, bit-equal to :func:`msbfs_dist` on the same
     graph (distances are set-membership facts; only the dispatch shape of
     a level differs between backends).
+
+    Source ``i`` is bit ``i % 32`` of word ``i // 32`` of the packed
+    frontier (W = ceil(S / 32) words per vertex).
     """
     _check_k_max(k_max)
-    from ..kernels.msbfs_expand.ops import msbfs_step, pack_bits
-
     S = sources.shape[0]
     W = -(-S // 32)
-    INF = np.int8(INF_FOR(k_max))
-    idx = ell_in_idx[:n]                                   # drop sentinel row
     cols = jnp.arange(S)
-    seed_bits = jnp.zeros((n + 1, S), bool).at[sources, cols].set(True)
-    seed_bits = seed_bits.at[n].set(False)                 # sentinel stays 0
-    frontier = pack_bits(seed_bits)                        # (n+1, W)
-    visited = frontier[:n]                                 # seeds reached @0
-    dist = jnp.full((n, W * 32), INF, jnp.int8)
-    dist = dist.at[sources, cols].min(jnp.int8(0))
-    for hop in range(1, k_max + 1):
-        with jax.named_scope(f"msbfs.hop{hop}"):
-            frontier, visited, dist = msbfs_step(idx, frontier, visited,
-                                                 dist, hop, backend=backend)
-            frontier = jnp.concatenate(
-                [frontier, jnp.zeros((1, W), jnp.uint32)], axis=0)
-    dist = dist[:, :S]                                     # drop word padding
-    return jnp.concatenate([dist, jnp.full((1, S), INF, jnp.int8)], axis=0)
+    # distinct columns set distinct bits, so add == or, even where a
+    # source repeats; the sentinel row n is never a source
+    frontier = jnp.zeros((n + 1, W), jnp.uint32).at[sources, cols // 32].add(
+        jnp.uint32(1) << (cols % 32).astype(jnp.uint32))
+    return _packed_sweep(ell_in_idx[:n], frontier, S, k_max, backend)
 
 
 @partial(jax.jit, static_argnames=("n", "k_max", "backend"))
 def msbfs_set_dist_ell(ell_in_idx: jax.Array, seed_mask: jax.Array,
                        *, n: int, k_max: int,
                        backend: str = "jnp") -> jax.Array:
-    """Fused-kernel twin of :func:`msbfs_set_dist` (one bit column seeded
-    with the whole vertex set; 31 of the word's 32 lanes idle — the fused
-    dispatch still wins by collapsing the per-level op chain).
+    """Packed ELL twin of :func:`msbfs_set_dist` (one bit column seeded
+    with the whole vertex set; 31 of the word's 32 bits idle).
 
     seed_mask : (n+1,) int8 in {0,1} (row n must be 0).
     Returns (n+1,) int8 bit-equal to :func:`msbfs_set_dist`.
     """
     _check_k_max(k_max)
-    from ..kernels.msbfs_expand.ops import msbfs_step, pack_bits
-
-    INF = np.int8(INF_FOR(k_max))
-    idx = ell_in_idx[:n]
     seed = seed_mask.astype(bool).at[n].set(False)
-    frontier = pack_bits(seed[:, None])                    # (n+1, 1)
-    visited = frontier[:n]
-    dist = jnp.full((n, 32), INF, jnp.int8)
-    dist = dist.at[:, 0].set(jnp.where(seed[:n], jnp.int8(0), INF))
-    for hop in range(1, k_max + 1):
-        with jax.named_scope(f"msbfs.hop{hop}"):
-            frontier, visited, dist = msbfs_step(idx, frontier, visited,
-                                                 dist, hop, backend=backend)
-            frontier = jnp.concatenate(
-                [frontier, jnp.zeros((1, 1), jnp.uint32)], axis=0)
-    return jnp.concatenate([dist[:, 0], jnp.full((1,), INF, jnp.int8)])
+    frontier = seed.astype(jnp.uint32)[:, None]            # bit 0 of word 0
+    return _packed_sweep(ell_in_idx[:n], frontier, 1, k_max, backend)[:, 0]

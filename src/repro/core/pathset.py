@@ -70,40 +70,45 @@ def compact_rows(mask: jax.Array, payload: jax.Array, out_cap: int,
 
 
 @jax.jit
-def _concat2(a_verts, a_count, b_verts, b_count):
-    cap = a_verts.shape[0] + b_verts.shape[0]
-    width = a_verts.shape[1]
-    out = jnp.full((cap, width), -1, jnp.int32)
-    out = jax.lax.dynamic_update_slice(out, a_verts, (0, 0))
-    # mask invalid rows of b before placing at offset a_count
+def _place(out_verts, out_count, b_verts, b_count):
+    """Write b's valid rows at row ``out_count`` of ``out_verts``. The
+    caller sizes ``out_verts`` to hold every input's full capacity, so
+    the write never runs past the end."""
     bmask = jnp.arange(b_verts.shape[0])[:, None] < b_count
     b = jnp.where(bmask, b_verts, -1)
-    shifted = jnp.full((cap, width), -1, jnp.int32)
-    shifted = jax.lax.dynamic_update_slice(shifted, b, (a_count, 0))
-    out = jnp.where(jnp.arange(cap)[:, None] < a_count, out, shifted)
-    return out, a_count + b_count
+    out = jax.lax.dynamic_update_slice(out_verts, b, (out_count, 0))
+    return out, out_count + b_count
 
 
 def concat(sets: list[PathSet]) -> PathSet:
-    """Concatenate packed PathSets (same width) into one packed PathSet."""
+    """Concatenate packed PathSets (same width) into one packed PathSet.
+
+    The output capacity is the pow2 bucket of the summed input
+    capacities, so the per-input placement compiles once per (bucket,
+    input bucket, width) however many sets are joined.
+    """
     sets = [s for s in sets if s is not None]
     if not sets:
         raise ValueError("concat of no PathSets")
     if len(sets) == 1:
         return sets[0]
-    acc = sets[0]
+    from .graph import pow2_ceil
+
+    width = sets[0].verts.shape[1]
+    cap = pow2_ceil(sum(s.verts.shape[0] for s in sets))
+    verts = jnp.full((cap, width), -1, jnp.int32)
+    count = jnp.int32(0)
     ov = sets[0].overflow
-    for s in sets[1:]:
-        verts, count = _concat2(acc.verts, acc.count, s.verts, s.count)
+    for s in sets:
+        verts, count = _place(verts, count, s.verts, s.count)
         ov = ov | s.overflow
-        acc = PathSet(verts=verts, count=count, overflow=ov)
-    return acc
+    return PathSet(verts=verts, count=count, overflow=ov)
 
 
 def to_host(ps: PathSet) -> np.ndarray:
     """Valid rows as a host numpy array (n, L)."""
-    n = int(ps.count)
-    return np.asarray(ps.verts[:n])
+    # slicing on the host: a device slice would compile once per count
+    return np.asarray(ps.verts)[:int(ps.count)]
 
 
 class HostPathSet(NamedTuple):

@@ -225,7 +225,8 @@ class PathsStore:
     def host(self) -> np.ndarray:
         if self._host is None:
             with obstrace.span("transfer.paths", rows=self.count):
-                self._host = np.asarray(self._pathset.verts[:self.count])
+                # sliced on the host: a device slice compiles per count
+                self._host = np.asarray(self._pathset.verts)[:self.count]
             self._pathset = None   # release the padded device buffer
         return self._host
 

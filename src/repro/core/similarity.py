@@ -21,19 +21,26 @@ import jax.numpy as jnp
 import numpy as np
 
 from .index import QueryIndex
-from ..kernels.registry import resolve_backend
+from ..kernels.registry import KernelBackend, resolve_backend
 
 __all__ = ["gamma_matrix", "intersection_matrix", "similarity_matrix"]
 
 
+def _gamma_rows(dist: jax.Array, cols, ks: jax.Array) -> jax.Array:
+    """(Q, n) bool: row q marks the vertices within ks[q] hops of the
+    endpoint in column cols[q] of ``dist`` (sentinel row dropped)."""
+    return (dist[:-1, cols] <= ks[None, :]).T
+
+
+def _hop_budgets(index: QueryIndex) -> jax.Array:
+    return jnp.asarray(np.array([q[2] for q in index.queries], np.int8))
+
+
 def gamma_matrix(index: QueryIndex, reverse: bool = False) -> jax.Array:
     """(Q, n) bool — Γ_r if reverse else Γ."""
-    ks = jnp.asarray(np.array([q[2] for q in index.queries], np.int8))
     if reverse:
-        cols = index.dist_t[:-1, index.tgt_col]      # (n, Q)
-    else:
-        cols = index.dist_s[:-1, index.src_col]
-    return (cols <= ks[None, :]).T                   # (Q, n)
+        return _gamma_rows(index.dist_t, index.tgt_col, _hop_budgets(index))
+    return _gamma_rows(index.dist_s, index.src_col, _hop_budgets(index))
 
 
 @partial(jax.jit, static_argnames=("chunk",))
@@ -47,6 +54,21 @@ def intersection_matrix(gam: jax.Array, chunk: int = 1 << 16) -> jax.Array:
     return out.astype(jnp.int32)
 
 
+@partial(jax.jit, static_argnames=("backend",))
+def _gamma_stats(dist: jax.Array, cols: jax.Array, ks: jax.Array, *,
+                 backend: str) -> tuple[jax.Array, jax.Array]:
+    """(Q, Q) |Γ_A ∩ Γ_B| and (Q,) |Γ| for one direction, in one program:
+    the (Q, n) membership rows and their packed words exist only inside
+    it (run op by op they cost several (Q, n) buffers at once)."""
+    gam = _gamma_rows(dist, cols, ks)                       # (Q, n) bool
+    if KernelBackend(backend).uses_kernel:
+        from ..kernels.pairwise_popcount.ops import pairwise_intersections
+        inter = pairwise_intersections(gam, backend=backend)
+    else:
+        inter = intersection_matrix(gam)
+    return inter, jnp.sum(gam, axis=1, dtype=jnp.int32)
+
+
 def similarity_matrix(index: QueryIndex,
                       backend: Optional[str] = None) -> np.ndarray:
     """(Q, Q) float64 μ matrix on host (diagonal = 1).
@@ -55,18 +77,15 @@ def similarity_matrix(index: QueryIndex,
     unknown names raise ValueError): kernel backends run the packed
     AND+popcount kernel, ``jnp`` the chunked MXU matmul reference.
     """
-    gf = gamma_matrix(index, reverse=False)
-    gr = gamma_matrix(index, reverse=True)
-    kb = resolve_backend(backend)
-    if kb.uses_kernel:
-        from ..kernels.pairwise_popcount.ops import pairwise_intersections
-        inter_f = np.asarray(pairwise_intersections(gf, backend=kb.value))
-        inter_r = np.asarray(pairwise_intersections(gr, backend=kb.value))
-    else:
-        inter_f = np.asarray(intersection_matrix(gf))
-        inter_r = np.asarray(intersection_matrix(gr))
-    size_f = np.asarray(gf.sum(1)).astype(np.int64)
-    size_r = np.asarray(gr.sum(1)).astype(np.int64)
+    kb = resolve_backend(backend).value
+    ks = _hop_budgets(index)
+    inter_f, size_f = _gamma_stats(index.dist_s, jnp.asarray(index.src_col),
+                                   ks, backend=kb)
+    inter_r, size_r = _gamma_stats(index.dist_t, jnp.asarray(index.tgt_col),
+                                   ks, backend=kb)
+    inter_f, inter_r = np.asarray(inter_f), np.asarray(inter_r)
+    size_f = np.asarray(size_f).astype(np.int64)
+    size_r = np.asarray(size_r).astype(np.int64)
 
     def overlap(inter, size):
         mins = np.minimum(size[:, None], size[None, :]).astype(np.float64)

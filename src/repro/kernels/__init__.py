@@ -13,10 +13,11 @@ override) and a per-op dispatch table the ops wrappers register into.
 ``resolve_backend`` raises ``ValueError`` on unknown names — there is no
 silent fallback.
 
-This container is CPU-only: tests validate kernel bodies with
-interpret=True against ref.py across shape/dtype sweeps; the dry-run
-lowers the jnp backend (kernels cannot lower for the CPU backend), and the
-BlockSpecs document the VMEM tiling used on real TPU.
+Ops listed in ``registry.JNP_ONLY_OPS`` (the ELL row gathers) ship no
+kernel.py and run their jnp arm on every backend. On CPU, tests check the
+kernel bodies with interpret=True against ref.py across shape/dtype
+sweeps; ``tests/test_tpu_compile.py`` compiles every Pallas arm for a
+described TPU v5e at chip-sized widths.
 """
 from .registry import (KernelBackend, dispatch, register_op,  # noqa: F401
                        registered_ops, resolve_backend)

@@ -35,42 +35,43 @@ NEG_INF = -1e30
 
 def _make_kernel(block_k: int, causal: bool, scale: float, q_offset: int):
     def _kernel(q_ref, k_ref, v_ref, o_ref):
-        q = q_ref[...][0]                      # (BQ, Dh)
+        q = q_ref[0]                           # (BQ, Dh)
         S = k_ref.shape[1]
         BQ, Dh = q.shape
         q_blk = pl.program_id(1)
         q_off = q_blk * BQ
-        nk = pl.cdiv(S, block_k)
+        nk = S // block_k
 
         def body(kb, carry):
             acc, m, l = carry
-            k = jax.lax.dynamic_slice(k_ref[...][0], (kb * block_k, 0),
-                                      (block_k, Dh))
-            v = jax.lax.dynamic_slice(v_ref[...][0], (kb * block_k, 0),
-                                      (block_k, Dh))
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-            kv_pos = kb * block_k + jnp.arange(block_k)
-            mask = kv_pos[None, :] < S
+            rows = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+            k = k_ref[0, rows, :]              # (BK, Dh)
+            v = v_ref[0, rows, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            kv_pos = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (BQ, block_k), 1)
             if causal:
                 # q_offset aligns decode-style queries (Sq < Skv) to the
                 # tail of the KV axis, matching the reference.
-                q_pos = q_off + jnp.arange(BQ) + q_offset
-                mask = mask & (kv_pos[None, :] <= q_pos[:, None])
-            s = jnp.where(mask, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None])
+                q_pos = q_off + q_offset + jax.lax.broadcasted_iota(
+                    jnp.int32, (BQ, block_k), 0)
+                s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
-            acc = acc * alpha[:, None] + jnp.dot(
+            acc = acc * alpha + jnp.dot(
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            l = l * alpha + jnp.sum(p, axis=1)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
             return acc, m_new, l
 
         acc0 = jnp.zeros((BQ, Dh), jnp.float32)
-        m0 = jnp.full((BQ,), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((BQ,), jnp.float32)
+        m0 = jnp.full((BQ, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((BQ, 1), jnp.float32)
         acc, m, l = jax.lax.fori_loop(0, nk, body, (acc0, m0, l0))
-        out = acc / jnp.maximum(l, 1e-30)[:, None]
-        o_ref[...] = out[None].astype(o_ref.dtype)
+        out = acc / jnp.maximum(l, 1e-30)
+        o_ref[0] = out.astype(o_ref.dtype)
     return _kernel
 
 
@@ -86,10 +87,13 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     BH, Sq, Dh = q.shape
     Skv = k.shape[1]
     bq = min(block_q, Sq)
+    bk = min(block_k, Skv)
+    if Skv % bk:
+        raise ValueError(f"Skv={Skv} must be a multiple of block_k={bk}")
     scale = 1.0 / (Dh ** 0.5)
     grid = (BH, pl.cdiv(Sq, bq))
     return pl.pallas_call(
-        _make_kernel(min(block_k, Skv), causal, scale, Skv - Sq),
+        _make_kernel(bk, causal, scale, Skv - Sq),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, Dh), lambda b, i: (b, i, 0)),

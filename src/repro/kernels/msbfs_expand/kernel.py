@@ -1,20 +1,19 @@
-"""Pallas kernel: bit-packed multi-source BFS frontier expansion.
+"""Pallas kernel: per-level distance count from a packed reached-set.
 
-    next[v, w] = OR over d of frontier[ell_idx[v, d], w]
+    count[32w + b, v] += 1 - bit b of words[w, v]
 
-The paper's BuildIndex hop adapted to the TPU memory hierarchy:
-  * frontiers are bit-packed uint32 words -- 32 BFS sources per lane, the
-    MS-BFS [36] trick; one VPU OR handles 32 sources at once.
-  * the graph is padded ELL, so the gather is a *regular* row gather
-    (vector index + static column range) instead of CSR pointer chasing.
-  * grid = (row blocks, word blocks). Each program owns a (BV, BW) output
-    tile; the full frontier word-slice (V+1, BW) is resident in VMEM
-    (VMEM budget: (V_shard+1) * BW * 4B -- e.g. 128k rows x 8 words = 4 MB;
-    the launcher shards vertices across devices to keep this bounded and
-    the ELL tile streams in at (BV, D) * 4B).
+The packed MS-BFS sweep (core/msbfs.py) keeps, per source, the number of
+levels at which a vertex was still unreached; that number is the
+distance. Vertices lie on the lane axis of both operands, so word ``w``
+unpacks into the 32 sublane rows ``32w .. 32w + 31`` of the int8 count:
+one (32, BV) int8 tile per word, with no relayout. Unpacking the same
+bits in XLA materializes a (W, 32, V) uint32 broadcast, 8 GiB per level
+at 2^22 vertices and 16 words.
 
-Sentinel: ell row entries equal to V point at frontier row V, which the
-wrapper pins to zero words, so padding contributes nothing to the OR.
+Tiling: grid = (vertex blocks,); each program reads a (W, BV) int32 word
+tile and rewrites its (32*W, BV) int8 count tile in place (aliased).
+VMEM: (W*4 + 2*32*W) * BV bytes, double-buffered: 9 MiB at W=16,
+BV=4096.
 """
 from __future__ import annotations
 
@@ -24,119 +23,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["msbfs_expand_pallas", "msbfs_step_pallas"]
+__all__ = ["unreached_count_pallas"]
 
 
-def _kernel(idx_ref, fr_ref, out_ref):
-    idx = idx_ref[...]                       # (BV, D) int32
-    fr = fr_ref[...]                         # (V+1, BW) uint32
-    D = idx.shape[1]
-
-    def body(d, acc):
-        rows = jax.lax.dynamic_index_in_dim(idx, d, axis=1, keepdims=False)
-        return acc | fr[rows]                # row gather + OR
-
-    acc0 = jnp.zeros(out_ref.shape, jnp.uint32)
-    out_ref[...] = jax.lax.fori_loop(0, D, body, acc0)
+def _count_kernel(w_ref, c_ref, o_ref):
+    W, bv = w_ref.shape
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (32, bv), 0)
+    for w in range(W):
+        row = w_ref[w:w + 1, :]                              # (1, BV)
+        clear = (jax.lax.shift_right_logical(row, shifts) & 1) ^ 1
+        rows = slice(32 * w, 32 * w + 32)
+        o_ref[rows, :] = (c_ref[rows, :].astype(jnp.int32)
+                          + clear).astype(jnp.int8)
 
 
-@functools.partial(jax.jit, static_argnames=("block_v", "block_w", "interpret"))
-def msbfs_expand_pallas(ell_idx: jax.Array, frontier: jax.Array,
-                        *, block_v: int = 256, block_w: int = 8,
-                        interpret: bool = False) -> jax.Array:
-    """ell_idx: (V, D) int32 (pad = V); frontier: (V+1, W) uint32 (row V = 0).
-
-    Returns next frontier words (V, W) uint32 (un-sentineled).
-    """
-    V, D = ell_idx.shape
-    W = frontier.shape[1]
+@functools.partial(jax.jit, static_argnames=("block_v", "interpret"))
+def unreached_count_pallas(words_t: jax.Array, count: jax.Array, *,
+                           block_v: int = 4096,
+                           interpret: bool = False) -> jax.Array:
+    """words_t: (W, V) int32 packed reached-set, vertex-minor (the bits of
+    a uint32 word); count: (32*W, V) int8. Returns ``count`` plus one
+    wherever the matching bit is clear."""
+    W, V = words_t.shape
     bv = min(block_v, V)
-    bw = min(block_w, W)
-    grid = (pl.cdiv(V, bv), pl.cdiv(W, bw))
     return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bv, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((V + 1, bw), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bv, bw), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((V, W), jnp.uint32),
+        _count_kernel,
+        grid=(pl.cdiv(V, bv),),
+        in_specs=[pl.BlockSpec((W, bv), lambda i: (0, i)),
+                  pl.BlockSpec((32 * W, bv), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((32 * W, bv), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct(count.shape, jnp.int8),
+        input_output_aliases={1: 0},
         interpret=interpret,
-    )(ell_idx, frontier)
-
-
-def _step_kernel(hop, idx_ref, fr_ref, vis_ref, dist_ref,
-                 nf_ref, vo_ref, do_ref):
-    idx = idx_ref[...]                       # (BV, D) int32
-    fr = fr_ref[...]                         # (V+1, BW) uint32
-    D = idx.shape[1]
-
-    def body(d, acc):
-        rows = jax.lax.dynamic_index_in_dim(idx, d, axis=1, keepdims=False)
-        return acc | fr[rows]
-
-    acc = jax.lax.fori_loop(0, D, body, jnp.zeros(nf_ref.shape, jnp.uint32))
-    vis = vis_ref[...]                       # (BV, BW) uint32
-    new = acc & ~vis                         # dedup against the visited set
-    nf_ref[...] = new
-    vo_ref[...] = vis | new
-    # unpack the freshly-set bits (little-endian within a word, matching
-    # ref.pack_bits) and stamp the hop into the distance tile
-    bv, bw = new.shape
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = ((new[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)) != 0
-    do_ref[...] = jnp.where(bits.reshape(bv, bw * 32), jnp.int8(hop),
-                            dist_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("hop", "block_v", "block_w",
-                                             "interpret"))
-def msbfs_step_pallas(ell_idx: jax.Array, frontier: jax.Array,
-                      visited: jax.Array, dist: jax.Array, *, hop: int,
-                      block_v: int = 256, block_w: int = 8,
-                      interpret: bool = False):
-    """One fused MS-BFS level: expand + visited dedup + distance write.
-
-    ell_idx  : (V, D) int32 in-neighbor table (pad = V)
-    frontier : (V+1, W) uint32 packed level-(hop-1) frontier (row V = 0)
-    visited  : (V, W) uint32 packed reached-set (hop-0 seeds included)
-    dist     : (V, W*32) int8 distances, bit (v, w*32+b) <-> word bit
-    hop      : static level being written (the per-k_max loop is unrolled
-               under jit, so this is a compile-time constant)
-
-    Returns (next_frontier (V, W), visited | next (V, W),
-    dist with ``hop`` stamped where a new bit was set) — ONE device
-    dispatch where the segment-op path issues gather + segment_max +
-    mask-mul + where per level.
-
-    Tiling mirrors :func:`msbfs_expand_pallas`; the distance tile is the
-    (BV, BW*32) byte block aligned with the word block, so all three
-    outputs stream through the same grid.
-    """
-    V, D = ell_idx.shape
-    W = frontier.shape[1]
-    bv = min(block_v, V)
-    bw = min(block_w, W)
-    grid = (pl.cdiv(V, bv), pl.cdiv(W, bw))
-    return pl.pallas_call(
-        functools.partial(_step_kernel, hop),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bv, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((V + 1, bw), lambda i, j: (0, j)),
-            pl.BlockSpec((bv, bw), lambda i, j: (i, j)),
-            pl.BlockSpec((bv, bw * 32), lambda i, j: (i, j)),
-        ],
-        out_specs=(
-            pl.BlockSpec((bv, bw), lambda i, j: (i, j)),
-            pl.BlockSpec((bv, bw), lambda i, j: (i, j)),
-            pl.BlockSpec((bv, bw * 32), lambda i, j: (i, j)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((V, W), jnp.uint32),
-            jax.ShapeDtypeStruct((V, W), jnp.uint32),
-            jax.ShapeDtypeStruct((V, W * 32), jnp.int8),
-        ),
-        interpret=interpret,
-    )(ell_idx, frontier, visited, dist)
+    )(words_t, count)

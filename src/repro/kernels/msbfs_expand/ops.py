@@ -1,30 +1,25 @@
-"""Public wrappers: packed MS-BFS hop and the fused per-level step."""
+"""Public wrappers: packed MS-BFS hop and the per-level step."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 from ..registry import BackendLike, dispatch, register_op
-from .kernel import msbfs_expand_pallas, msbfs_step_pallas
-from .ref import msbfs_expand_ref, msbfs_step_ref, pack_bits, unpack_bits
+from .kernel import unreached_count_pallas
+from .ref import (msbfs_expand_ref, msbfs_step_ref, pack_bits, unpack_bits,
+                  unreached_count_ref)
 
-__all__ = ["msbfs_hop_packed", "msbfs_step", "pack_bits", "unpack_bits"]
+__all__ = ["msbfs_hop_packed", "msbfs_step", "unreached_count", "pack_bits",
+           "unpack_bits"]
 
 
+register_op("msbfs_expand", jnp=msbfs_expand_ref)
+register_op("msbfs_step", jnp=msbfs_step_ref)
 register_op(
-    "msbfs_expand",
-    pallas=msbfs_expand_pallas,
-    interpret=lambda ell, fw: msbfs_expand_pallas(ell, fw, interpret=True),
-    jnp=msbfs_expand_ref,
-)
-
-register_op(
-    "msbfs_step",
-    pallas=lambda ell, fw, vis, dist, hop: msbfs_step_pallas(
-        ell, fw, vis, dist, hop=hop),
-    interpret=lambda ell, fw, vis, dist, hop: msbfs_step_pallas(
-        ell, fw, vis, dist, hop=hop, interpret=True),
-    jnp=msbfs_step_ref,
+    "msbfs_count",
+    pallas=unreached_count_pallas,
+    interpret=lambda w, c: unreached_count_pallas(w, c, interpret=True),
+    jnp=unreached_count_ref,
 )
 
 
@@ -41,13 +36,20 @@ def msbfs_hop_packed(ell_idx: jax.Array, frontier_words: jax.Array,
 
 
 def msbfs_step(ell_idx: jax.Array, frontier: jax.Array, visited: jax.Array,
-               dist: jax.Array, hop: int,
                backend: BackendLike = None):
-    """One fused MS-BFS level (expand + dedup + distance write).
+    """One MS-BFS level (expand + dedup against ``visited``).
 
-    See :func:`~repro.kernels.msbfs_expand.kernel.msbfs_step_pallas` for
-    shapes; ``hop`` must be a static Python int (the engine unrolls the
-    k_max loop under jit). Returns (next_frontier, visited, dist).
+    See :func:`~repro.kernels.msbfs_expand.ref.msbfs_step_ref` for shapes.
+    Returns (next_frontier, visited | next_frontier).
     """
-    return dispatch("msbfs_step", backend)(ell_idx, frontier, visited,
-                                           dist, hop)
+    return dispatch("msbfs_step", backend)(ell_idx, frontier, visited)
+
+
+def unreached_count(visited: jax.Array, count: jax.Array,
+                    backend: BackendLike = None) -> jax.Array:
+    """Add one to ``count[i, v]`` wherever source ``i`` has not reached
+    vertex ``v``: visited (V, W) uint32 packed as :func:`pack_bits`
+    (source ``i`` = bit ``i % 32`` of word ``i // 32``); count
+    (32*W, V) int8, source-major."""
+    words_t = jax.lax.bitcast_convert_type(visited.T, jnp.int32)
+    return dispatch("msbfs_count", backend)(words_t, count)

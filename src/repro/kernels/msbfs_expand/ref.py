@@ -1,10 +1,26 @@
-"""Pure-jnp oracle for the bit-packed MS-BFS expansion + pack/unpack helpers."""
+"""Bit-packed MS-BFS expansion over the padded ELL table (jnp) and the
+pack/unpack helpers.
+
+    next[v, w] = OR over d of frontier[ell_idx[v, d], w]
+
+Frontiers are bit-packed uint32 words, 32 BFS sources per word (the MS-BFS
+[36] trick): one OR handles 32 sources at once. The graph is padded ELL,
+so the expansion is a regular row gather, taken one ELL column at a time:
+the live gather stays (V, W) words and never becomes (V, D, W), which at
+2^22 vertices would not fit the device. The expansion has no Pallas arm
+(see ``JNP_ONLY_OPS`` in :mod:`repro.kernels.registry`); the per-level
+distance count (:func:`unreached_count_ref`) has one (kernel.py).
+
+Sentinel: ell row entries equal to V point at frontier row V, which the
+callers pin to zero words, so padding contributes nothing to the OR.
+"""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["msbfs_expand_ref", "msbfs_step_ref", "pack_bits", "unpack_bits"]
+__all__ = ["msbfs_expand_ref", "msbfs_step_ref", "unreached_count_ref",
+           "pack_bits", "unpack_bits"]
 
 
 def pack_bits(bits: jax.Array) -> jax.Array:
@@ -27,21 +43,36 @@ def unpack_bits(words: jax.Array, S: int) -> jax.Array:
 
 
 def msbfs_expand_ref(ell_idx: jax.Array, frontier: jax.Array) -> jax.Array:
-    """OR-gather over padded ELL rows: next[v, w] = OR_d frontier[idx[v,d], w]."""
-    gathered = frontier[ell_idx]               # (V, D, W)
-    return jax.lax.reduce(gathered, jnp.uint32(0), jax.lax.bitwise_or, (1,))
+    """ell_idx: (V, D) int32 (pad = V); frontier: (V+1, W) uint32 (row V = 0).
+
+    Returns next[v, w] = OR_d frontier[ell_idx[v, d], w], (V, W) uint32.
+    """
+    def column(acc, rows):
+        return acc | frontier.at[rows].get(mode="promise_in_bounds"), None
+
+    acc0 = jnp.zeros((ell_idx.shape[0], frontier.shape[1]), jnp.uint32)
+    return jax.lax.scan(column, acc0, ell_idx.T)[0]
 
 
 def msbfs_step_ref(ell_idx: jax.Array, frontier: jax.Array,
-                   visited: jax.Array, dist: jax.Array, hop: int):
-    """jnp twin of the fused step: expand, dedup vs visited, stamp hop.
+                   visited: jax.Array):
+    """One MS-BFS level: expand, then dedup against the visited set.
 
-    Shapes as :func:`~repro.kernels.msbfs_expand.kernel.msbfs_step_pallas`.
+    ell_idx  : (V, D) int32 in-neighbour table (pad = V)
+    frontier : (V+1, W) uint32 packed level-(hop-1) frontier (row V = 0)
+    visited  : (V, W) uint32 packed reached-set (hop-0 seeds included)
+
+    Returns (next_frontier, visited | next_frontier), both (V, W).
     """
-    acc = msbfs_expand_ref(ell_idx, frontier)            # (V, W)
-    new = acc & ~visited
-    V, W = new.shape
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = ((new[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1)) != 0
-    dist = jnp.where(bits.reshape(V, W * 32), jnp.int8(hop), dist)
-    return new, visited | new, dist
+    new = msbfs_expand_ref(ell_idx, frontier) & ~visited
+    return new, visited | new
+
+
+def unreached_count_ref(words_t: jax.Array, count: jax.Array) -> jax.Array:
+    """jnp twin of :func:`~repro.kernels.msbfs_expand.kernel.
+    unreached_count_pallas`: ``count[32w + b, v] += 1 - bit b of
+    words_t[w, v]``. words_t: (W, V) int32; count: (32*W, V) int8."""
+    W = words_t.shape[0]
+    shifts = jnp.arange(32, dtype=jnp.int32)[None, :, None]
+    bits = jax.lax.shift_right_logical(words_t[:, None, :], shifts) & 1
+    return count + (1 - bits).astype(jnp.int8).reshape(32 * W, -1)

@@ -5,16 +5,17 @@
 The enumeration hot spot (Fig 3c: join/scan dominates): joining forward and
 backward half-paths requires, for every candidate pair, the simple-path
 check "do the two halves share a vertex?". On CPU that is a hash probe per
-pair; here it is a dense (BA, BB, LA, LB) equality reduction — regular,
-vectorizable, and tiny in the L dimensions (L <= 9), so the VPU runs it at
-full tilt. The wrapper derives join validity:
+pair; here it is an equality reduction over the tiny L dimensions
+(L <= 9): a static loop over the path columns, each step one 2-D
+(BA, BB) compare, so every op is a plain VPU tile op that Mosaic lowers.
+The wrapper derives join validity:
 
   keyed join  : valid = key match (last cols) & overlap == 1 (join vertex only)
   splice join : valid = overlap == 0 (prefix vs cached suffix are disjoint)
 
 Tiling: grid = (A blocks, B blocks); each program owns a (BA, BB) int32
-tile; A tile (BA, LA) and B tile (BB, LB) are VMEM-resident
-(BA=BB=256, L=9 -> ~18 KB in, 256 KB out).
+tile; the A tile (BA, LA) and the transposed B tile (LB, BB) are
+VMEM-resident (BA=BB=256, L=9 -> ~18 KB in, 256 KB out).
 """
 from __future__ import annotations
 
@@ -28,11 +29,17 @@ __all__ = ["path_overlap_pallas", "rowwise_overlap_pallas",
            "path_member_pallas"]
 
 
-def _kernel(a_ref, b_ref, out_ref):
+def _kernel(a_ref, bt_ref, out_ref):
     a = a_ref[...]                            # (BA, LA) int32
-    b = b_ref[...]                            # (BB, LB) int32
-    eq = (a[:, None, :, None] == b[None, :, None, :]) & (a >= 0)[:, None, :, None]
-    out_ref[...] = jnp.sum(eq.astype(jnp.int32), axis=(2, 3))
+    bt = bt_ref[...]                          # (LB, BB) int32
+    acc = jnp.zeros(out_ref.shape, jnp.int32)
+    for p in range(a.shape[1]):
+        ap = a[:, p:p + 1]                    # (BA, 1)
+        hit = jnp.zeros(out_ref.shape, jnp.int32)
+        for q in range(bt.shape[0]):
+            hit += (ap == bt[q:q + 1, :]).astype(jnp.int32)   # (BA, BB)
+        acc += jnp.where(ap >= 0, hit, 0)
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block_a", "block_b", "interpret"))
@@ -50,20 +57,22 @@ def path_overlap_pallas(a_verts: jax.Array, b_verts: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((ba, LA), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, LB), lambda i, j: (j, 0)),
+            pl.BlockSpec((LB, bb), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((ba, bb), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((NA, NB), jnp.int32),
         interpret=interpret,
-    )(a_verts, b_verts)
+    )(a_verts, b_verts.T)
 
 
 def _rowwise_kernel(a_ref, b_ref, out_ref):
     a = a_ref[...]                            # (BN, LA) int32
     b = b_ref[...]                            # (BN, LB) int32
-    eq = (a[:, :, None] == b[:, None, :]) & (a >= 0)[:, :, None]
-    out_ref[...] = jnp.sum(eq.astype(jnp.int32), axis=(1, 2),
-                           keepdims=True)[:, :, 0]
+    acc = jnp.zeros(b.shape, jnp.int32)
+    for p in range(a.shape[1]):
+        ap = a[:, p:p + 1]                    # (BN, 1)
+        acc += ((ap == b) & (ap >= 0)).astype(jnp.int32)      # (BN, LB)
+    out_ref[...] = jnp.sum(acc, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -101,8 +110,10 @@ def rowwise_overlap_pallas(a_verts: jax.Array, b_verts: jax.Array,
 def _member_kernel(v_ref, c_ref, out_ref):
     v = v_ref[...]                            # (BN, L)  path prefixes
     c = c_ref[...]                            # (BN, D)  candidate vertices
-    eq = (c[:, :, None] == v[:, None, :])
-    out_ref[...] = jnp.sum(eq.astype(jnp.int32), axis=2)
+    acc = jnp.zeros(c.shape, jnp.int32)
+    for p in range(v.shape[1]):
+        acc += (c == v[:, p:p + 1]).astype(jnp.int32)         # (BN, D)
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))

@@ -11,6 +11,10 @@ implementations:
   ``jnp``       -- the pure-jnp segment-op reference twin (the default
                    everywhere off-TPU; property-tested bit-equal)
 
+The ops in :data:`JNP_ONLY_OPS` have no Pallas arm: they run their
+``jnp`` arm under every backend. That is a static choice, listed with its
+reason, never a run-time probe or fallback.
+
 Backend resolution order (``resolve_backend``):
 
   1. an explicit value (string or :class:`KernelBackend`) wins;
@@ -30,10 +34,11 @@ from __future__ import annotations
 import enum
 import importlib
 import os
-from typing import Callable, Union
+from typing import Callable, Optional, Union
 
 __all__ = ["KernelBackend", "BackendLike", "resolve_backend", "register_op",
-           "dispatch", "registered_ops", "op_manifest", "ENV_VAR"]
+           "dispatch", "registered_ops", "op_manifest", "ENV_VAR",
+           "JNP_ONLY_OPS"]
 
 ENV_VAR = "REPRO_KERNEL_BACKEND"
 
@@ -91,28 +96,56 @@ def resolve_backend(backend: BackendLike = None) -> KernelBackend:
 _OP_MODULES = {
     "msbfs_expand": "repro.kernels.msbfs_expand.ops",
     "msbfs_step": "repro.kernels.msbfs_expand.ops",
+    "msbfs_count": "repro.kernels.msbfs_expand.ops",
     "path_overlap": "repro.kernels.path_join.ops",
     "rowwise_overlap": "repro.kernels.path_join.ops",
     "path_member": "repro.kernels.path_join.ops",
-    "ell_spmm": "repro.kernels.ell_spmm.ops",
     "pairwise_popcount": "repro.kernels.pairwise_popcount.ops",
     "flash_attention": "repro.kernels.flash_attention.ops",
+}
+
+# Ops without a Pallas arm, and why. Each is a row gather over the padded
+# ELL table: out[v] = reduce over d of src[ell_idx[v, d]]. Mosaic has no
+# vector gather from a loaded value, and the source rows cannot sit in
+# VMEM: the bit-packed frontier of a 2^22-vertex graph at 512 sources is
+# 256 MiB (2 GiB with its 16-word rows padded to 128 lanes), against
+# 128 MiB of VMEM. Left in HBM, every neighbour costs one 64-byte row DMA:
+# V*D descriptors per level, issued one by one from the scalar core. XLA's
+# native gather serves the same access pattern, so these ops run their jnp
+# arm on every platform. That arm walks one ELL column per step, so the
+# live gather is (V, W) and never (V, D, W).
+_ELL_GATHER = ("row gather over the padded ELL table; no Mosaic vector "
+               "gather, source rows exceed VMEM (see registry.py)")
+JNP_ONLY_OPS: dict[str, str] = {
+    "msbfs_expand": _ELL_GATHER,
+    "msbfs_step": _ELL_GATHER,
 }
 
 _TABLE: dict[str, dict[KernelBackend, Callable]] = {}
 
 
-def register_op(name: str, *, pallas: Callable, interpret: Callable,
-                jnp: Callable) -> None:
-    """Register the three backend implementations of one op."""
-    _TABLE[name] = {KernelBackend.PALLAS: pallas,
-                    KernelBackend.INTERPRET: interpret,
-                    KernelBackend.JNP: jnp}
+def register_op(name: str, *, jnp: Callable,
+                pallas: Optional[Callable] = None,
+                interpret: Optional[Callable] = None) -> None:
+    """Register the backend implementations of one op: all three, or the
+    ``jnp`` arm alone for an op listed in :data:`JNP_ONLY_OPS`."""
+    if (pallas is None or interpret is None) != (name in JNP_ONLY_OPS) \
+            or (pallas is None) != (interpret is None):
+        raise ValueError(
+            f"op {name!r}: register pallas and interpret arms together, "
+            f"and only for ops not listed in JNP_ONLY_OPS")
+    _TABLE[name] = {KernelBackend.JNP: jnp}
+    if pallas is not None:
+        _TABLE[name].update({KernelBackend.PALLAS: pallas,
+                             KernelBackend.INTERPRET: interpret})
 
 
 def dispatch(name: str, backend: BackendLike = None) -> Callable:
-    """The implementation of op ``name`` for the resolved ``backend``."""
+    """The implementation of op ``name`` for the resolved ``backend``
+    (the ``jnp`` arm for an op in :data:`JNP_ONLY_OPS`)."""
     kb = resolve_backend(backend)
+    if name in JNP_ONLY_OPS:
+        kb = KernelBackend.JNP
     if name not in _TABLE:
         if name not in _OP_MODULES:
             raise KeyError(f"unknown kernel op {name!r}; registered ops: "

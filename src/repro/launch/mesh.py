@@ -4,53 +4,28 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_cells_mesh",
-           "mesh_by_name", "use_mesh"]
+__all__ = ["make_production_mesh", "make_host_mesh", "mesh_by_name",
+           "use_mesh"]
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    # jax >= 0.6 wants explicit axis types; older jax has no such kwarg
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def use_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh:
-    ``jax.set_mesh`` on modern jax, the Mesh context manager on older."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    """Context manager installing `mesh` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over local devices (CPU tests / smoke runs)."""
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kw(2))
-
-
-def make_cells_mesh(n_devices: int = 0):
-    """1-D "cells" mesh over the first N local devices — the layout the
-    sharded path engine expects (edge lists shard over "cells", sharing
-    clusters place on the flattened device list; see core.distributed).
-    ``n_devices=0`` takes every visible device. Works on both the classic
-    ``jax.sharding.Mesh`` constructor and the modern ``jax.make_mesh``
-    API (old jax has no make_mesh / axis_types)."""
-    import numpy as np
-
-    devs = jax.devices()
-    n = len(devs) if not n_devices else int(n_devices)
-    if n > len(devs):
-        raise ValueError(f"asked for {n} devices, have {len(devs)}")
-    if hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh((n,), ("cells",), **_axis_types_kw(1))
-        except TypeError:   # older make_mesh without axis_types support
-            pass
-    return jax.sharding.Mesh(np.array(devs[:n]), ("cells",))
+                         axis_types=(_AUTO, _AUTO))
 
 
 def mesh_by_name(name: str):

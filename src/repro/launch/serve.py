@@ -506,9 +506,10 @@ class StreamingServer:
             requeued_before = self.sched.requeued
             with self.engine.obs.span("serve.assemble",
                                       n_queries=len(batch)) as sasm:
-                index = build_index(
-                    self.engine.dg, [q.key for q in queries],
-                    backend=self.engine.kernel_backend.value)
+                # one index per micro-batch: it feeds the similarity here
+                # and every cluster's engine.run below
+                index = build_index(self.engine.dg, [q.key for q in queries],
+                                    backend=self.engine.kernel_backend.value)
                 mu = similarity_matrix(
                     index, backend=self.engine.kernel_backend.value)
                 bias = warm_cluster_bias(self.engine, queries,
@@ -541,7 +542,7 @@ class StreamingServer:
                 # loop — one run carries every (cache-aware) cluster,
                 # fanned across the per-device replicas and gathered back
                 r = self.engine.run(queries, planner=self.planner,
-                                    clusters=clusters)
+                                    clusters=clusters, index=index)
                 for i, qid in enumerate(qids):
                     self.results[qid] = r[i].offload()
                 for key in agg:
@@ -550,6 +551,7 @@ class StreamingServer:
             else:
                 cids = self.sched.submit([[qids[li] for li in cl]
                                           for cl in clusters])
+                local = {qid: li for li, qid in enumerate(qids)}
                 open_cids = set(cids)
                 while open_cids:
                     progressed = False
@@ -565,12 +567,19 @@ class StreamingServer:
                                 self.fail_injector(grp, item)
                             sub = [self._query_of[qid]
                                    for qid in item.queries]
+                            # an item requeued from an earlier micro-batch
+                            # is not in this batch's index
+                            sub_index = None
+                            if all(qid in local for qid in item.queries):
+                                sub_index = index.subset(
+                                    [local[qid] for qid in item.queries])
                             # the item IS one cluster — pass it through so
                             # the engine keeps our (cache-aware) grouping
                             # instead of re-clustering
                             r = self.engine.run(
                                 sub, planner=self.planner,
-                                clusters=[list(range(len(sub)))])
+                                clusters=[list(range(len(sub)))],
+                                index=sub_index)
                         except GroupFailure:
                             # the group died mid-item: mark it dead and
                             # requeue its in-flight cluster onto the

@@ -1,8 +1,6 @@
 """Opt-in ``jax.profiler`` integration for :mod:`repro.obs`.
 
-Everything here degrades to a no-op when jax (or the profiler plugin) is
-unavailable, so the zero-dep tracer/metrics layers never grow a hard jax
-edge. Three capabilities:
+Three capabilities:
 
 * **Span annotations on the device timeline** — :func:`attach` installs a
   ``jax.profiler.TraceAnnotation`` factory on a tracer, so every host
@@ -19,48 +17,27 @@ edge. Three capabilities:
   wires this around the streaming loop.
 * **Device-memory sampling** — :func:`sample_device_memory` reads
   ``device.memory_stats()`` into the ``device_bytes_in_use`` gauge
-  (labeled per device) and :func:`save_memory_profile` dumps the full
-  ``device_memory_profile`` pprof blob for offline digging. CPU backends
-  often report no memory stats; both return ``None`` rather than raise.
+  (labeled per device). The CPU backend reports no memory stats; the
+  function then returns ``None``.
 """
 from __future__ import annotations
 
 import contextlib
 from typing import Optional
 
+import jax
+import jax.profiler as prof
+
 from . import metrics as _metrics
 from . import trace as _trace
 
-__all__ = ["available", "annotation", "annotation_factory", "attach",
-           "detach", "start_trace", "stop_trace", "profile_run",
-           "sample_device_memory", "save_memory_profile"]
-
-
-def _profiler():
-    try:
-        import jax.profiler as prof
-        return prof
-    except Exception:
-        return None
-
-
-def available() -> bool:
-    """True when ``jax.profiler`` can be imported."""
-    return _profiler() is not None
+__all__ = ["annotation_factory", "attach", "detach", "start_trace",
+           "stop_trace", "profile_run", "sample_device_memory"]
 
 
 def annotation_factory():
-    """Return a ``name -> context manager`` factory for span annotation
-    (``TraceAnnotation`` when available, else null contexts)."""
-    prof = _profiler()
-    if prof is not None and hasattr(prof, "TraceAnnotation"):
-        return prof.TraceAnnotation
-    return lambda name: contextlib.nullcontext()
-
-
-def annotation(name: str):
-    """A single named annotation context (convenience wrapper)."""
-    return annotation_factory()(name)
+    """The ``name -> context manager`` factory for span annotation."""
+    return prof.TraceAnnotation
 
 
 def attach(tracer: Optional[_trace.Tracer] = None) -> _trace.Tracer:
@@ -77,31 +54,26 @@ def detach(tracer: Optional[_trace.Tracer] = None) -> _trace.Tracer:
     return tr
 
 
-def start_trace(logdir: str) -> bool:
-    """Start an XLA profiler capture into a TensorBoard logdir; returns
-    False (no-op) when the profiler is unavailable."""
-    prof = _profiler()
-    if prof is None:
-        return False
+def start_trace(logdir: str) -> None:
+    """Start an XLA profiler capture into a TensorBoard logdir."""
     prof.start_trace(logdir)
-    return True
 
 
 def stop_trace() -> None:
-    prof = _profiler()
-    if prof is not None:
-        prof.stop_trace()
+    prof.stop_trace()
 
 
 @contextlib.contextmanager
 def profile_run(logdir: Optional[str]):
     """Bracket a block with start/stop_trace when ``logdir`` is set."""
-    started = bool(logdir) and start_trace(logdir)
+    if not logdir:
+        yield False
+        return
+    start_trace(logdir)
     try:
-        yield started
+        yield True
     finally:
-        if started:
-            stop_trace()
+        stop_trace()
 
 
 def sample_device_memory(reg: Optional[_metrics.MetricsRegistry] = None
@@ -109,36 +81,15 @@ def sample_device_memory(reg: Optional[_metrics.MetricsRegistry] = None
     """Sample per-device bytes-in-use into ``device_bytes_in_use`` gauges.
 
     Returns the total bytes across devices, or ``None`` when no device
-    reports memory stats (typical for the CPU backend).
+    reports memory stats (the CPU backend).
     """
-    try:
-        import jax
-        devices = jax.devices()
-    except Exception:
-        return None
     reg = reg if reg is not None else _metrics.registry()
     total = None
-    for d in devices:
-        try:
-            stats = d.memory_stats()
-        except Exception:
-            stats = None
-        if not stats:
+    for d in jax.devices():
+        stats = d.memory_stats()
+        if not stats or "bytes_in_use" not in stats:
             continue
-        used = stats.get("bytes_in_use")
-        if used is None:
-            continue
+        used = int(stats["bytes_in_use"])
         reg.gauge("device_bytes_in_use", device=str(d)).set(used)
-        total = (total or 0) + int(used)
+        total = (total or 0) + used
     return total
-
-
-def save_memory_profile(path: str) -> bool:
-    """Write the pprof-format ``device_memory_profile`` blob to ``path``."""
-    prof = _profiler()
-    if prof is None:
-        return False
-    blob = prof.device_memory_profile()
-    with open(path, "wb") as f:
-        f.write(blob)
-    return True

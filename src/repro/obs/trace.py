@@ -25,9 +25,8 @@ Design points:
   before the device work it launched finishes. ``Span.fence(value)``
   marks arrays to ``block_until_ready`` at span exit *when the tracer was
   enabled with* ``fence=True`` — attribution at the cost of overlap, off
-  by default so traced serving keeps its pipelining. The block function
-  is injected lazily (jax import only on first fenced exit), keeping this
-  module importable with no third-party dependency.
+  by default so traced serving keeps its pipelining. jax is imported on
+  the first fenced exit only, so this module imports without it.
 * **Chrome-trace export.** :meth:`Tracer.export` writes the standard
   ``traceEvents`` JSON that chrome://tracing and https://ui.perfetto.dev
   open directly; :func:`summarize` / :func:`coverage` aggregate a saved
@@ -148,7 +147,6 @@ class Tracer:
         self._buf: deque = deque(maxlen=int(capacity))
         self._local = threading.local()
         self.t_origin = time.perf_counter()
-        self._block_fn: Optional[Callable] = None
 
     # -- span creation -------------------------------------------------
     def span(self, name: str, **attrs) -> Span:
@@ -164,13 +162,8 @@ class Tracer:
         self._buf.append(sp)
 
     def _block(self, value) -> None:
-        if self._block_fn is None:
-            try:
-                import jax
-                self._block_fn = jax.block_until_ready
-            except Exception:            # fencing degrades to a no-op
-                self._block_fn = lambda v: v
-        self._block_fn(value)
+        import jax
+        jax.block_until_ready(value)
 
     # -- lifecycle -----------------------------------------------------
     def configure(self, *, enabled: Optional[bool] = None,

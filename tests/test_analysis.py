@@ -228,8 +228,8 @@ class TestJaxprAudit:
         budgets = json.loads((REPO / DEFAULT_BUDGETS_PATH).read_text())
         # satellite: the fused expand_level budget is committed
         assert "expand_level" in budgets
-        # acceptance: the fused MS-BFS sweep stays at ONE kernel dispatch
-        # per level on the kernel backend
+        # acceptance: the packed MS-BFS sweep stays at ONE kernel dispatch
+        # (the msbfs_count distance update) per level on the kernel backend
         for fn in ("msbfs_dist_ell", "msbfs_set_dist_ell"):
             assert budgets[fn]["interpret"][
                 "kernel_dispatches_per_level"] == 1

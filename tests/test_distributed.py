@@ -2,28 +2,19 @@
 
 Two families:
 
-* sharded-engine tests (`core.distributed`): run on any jax with the
-  classic ``jax.sharding.Mesh`` + ``NamedSharding`` GSPMD API. The
-  multi-device ones run under 8 fake CPU devices via subprocess — the
-  XLA device-count flag must be set before jax initializes, so they get
+* sharded-engine tests (`core.distributed`) on the ``jax.sharding.Mesh``
+  + ``NamedSharding`` GSPMD API. The multi-device ones run under 8 fake
+  CPU devices via subprocess — the XLA device-count flag must be set before jax initializes, so they get
   isolated interpreters; the placement/identity unit tests run in-process
   on the single default device (a mesh of size 1 is the identity).
-* legacy model-stack tests marked ``modern_jax`` (flash decode,
-  checkpoint reshard, ring aggregate): need jax.make_mesh axis_types /
-  jax.set_mesh / jax.shard_map and skip on older jax.
+* model-stack tests (flash decode, checkpoint reshard, ring aggregate)
+  on jax.make_mesh axis_types / jax.set_mesh / jax.shard_map.
 """
 import subprocess
 import sys
 import textwrap
 
 import numpy as np
-import jax
-import pytest
-
-modern_jax = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="needs the modern jax sharding API (jax.make_mesh axis_types, "
-           "jax.set_mesh, jax.shard_map); installed jax is too old")
 
 
 def _run(code: str) -> str:
@@ -44,7 +35,6 @@ sys.path.insert(0, "src")
 """
 
 
-@modern_jax
 def test_flash_decode_matches_baseline():
     """shard_map flash-decoding == gathered-KV decode on a (2, 4) mesh."""
     out = _run(PREAMBLE + """
@@ -121,7 +111,6 @@ assert np.array_equal(ref, dist)
     assert "EQ True" in out
 
 
-@modern_jax
 def test_elastic_checkpoint_reshard():
     """Save on a (4,2) mesh, restore onto (2,2) — elastic scaling."""
     out = _run(PREAMBLE + """
@@ -150,7 +139,6 @@ print("RESHARD OK")
     assert "RESHARD OK" in out
 
 
-@modern_jax
 def test_ring_aggregate_matches_segment_sum():
     """GNN ring SpMM (collective_permute schedule) == local segment_sum."""
     out = _run(PREAMBLE + """
@@ -245,40 +233,6 @@ def test_edge_bucket_alignment():
     assert edge_bucket_for(1024, 8) == 1024
     assert edge_bucket_for(1000, 6) % 6 == 0         # non-pow2 aligns
     assert edge_bucket_for(1000, 6) >= 1024
-
-
-def test_sentinel_pad_not_edge_repeat_in_walk_counts():
-    """The device-multiple pad must be the inert sentinel (n, n), not a
-    repeat of the last real edge — a repeated edge double-counts in
-    walk_counts (segment_sum), even though it is invisible to the
-    boolean-semiring BFS. This is the host-side half of the shard_edges
-    fix; the sharded tail itself is asserted under the 8-device mesh in
-    test_distributed_msbfs_matches_single_device."""
-    import jax.numpy as jnp
-    from repro.core import generators
-    from repro.core.graph import DeviceGraph, pad_edge_list
-    from repro.core.index import walk_counts
-
-    g = generators.erdos(96, 3.0, seed=3)
-    dg = DeviceGraph.build(g, pad=False)     # exact shapes
-    slack = jnp.full((g.n + 1,), 7, jnp.int8)
-    # source = the repeated edge's own src, so the duplicated edge is
-    # guaranteed to lie on counted walks (level 1 already diverges)
-    src = int(np.asarray(dg.esrc)[-1])
-    exact = np.asarray(walk_counts(dg.esrc, dg.edst, src, slack,
-                                   n=g.n, budget=3))
-    # sentinel pad (what shard_edges now uses): bit-equal counts
-    pe, pd = pad_edge_list(np.asarray(dg.esrc), np.asarray(dg.edst),
-                           g.n, g.m + 13)
-    padded = np.asarray(walk_counts(jnp.asarray(pe), jnp.asarray(pd), src,
-                                    slack, n=g.n, budget=3))
-    assert np.array_equal(exact, padded)
-    # the old repeat-last-edge pad really does diverge (double count)
-    re_ = np.concatenate([np.asarray(dg.esrc)] + [np.asarray(dg.esrc)[-1:]] * 13)
-    rd_ = np.concatenate([np.asarray(dg.edst)] + [np.asarray(dg.edst)[-1:]] * 13)
-    repeat = np.asarray(walk_counts(jnp.asarray(re_), jnp.asarray(rd_), src,
-                                    slack, n=g.n, budget=3))
-    assert not np.array_equal(exact, repeat)
 
 
 def test_mesh_size_one_is_identity():

@@ -120,3 +120,79 @@ def test_n_dedup_counts_per_direction():
             seen_t.add(t)
     res = eng.process(distinct, mode="batch")
     assert res.stats["n_dedup"] == 0
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_splice_dag_drops_exactly_the_cycle_closing_edges(seed):
+    """Incremental topological order vs a full reachability test on every
+    insert: the same edges are kept, in the same per-node order."""
+    import numpy as np
+    from repro.core.detect import PlanNode, _SpliceDAG
+
+    r = np.random.default_rng(seed)
+    nodes = [PlanNode(nid=i, src=i, budget=0, query=None) for i in range(5)]
+    ref = {i: [] for i in range(5)}            # parent -> spliced children
+    dag = _SpliceDAG(nodes)
+
+    def reaches(a, b):                          # path a ->* b in ref?
+        seen, stack = set(), [a]
+        while stack:
+            x = stack.pop()
+            if x == b:
+                return True
+            for y in ref[x]:
+                if y not in seen:
+                    seen.add(y)
+                    stack.append(y)
+        return False
+
+    for _ in range(400):
+        if r.random() < 0.05:
+            nid = len(nodes)
+            nodes.append(PlanNode(nid=nid, src=nid, budget=0, query=None))
+            dag.append()
+            ref[nid] = []
+        child, parent = (int(x) for x in r.integers(0, len(nodes), 2))
+        dag.add_edge(child, parent)
+        if child != parent and child not in ref[parent] \
+                and not reaches(child, parent):
+            ref[parent].append(child)
+    assert {n.nid: n.in_edges for n in nodes} == ref
+    order = dag.ord
+    assert all(order[p] < order[c] for p in ref for c in ref[p])
+
+
+def test_splice_hits_marks_roots_with_a_trigger():
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core.enumerate import splice_hits
+
+    n = 10
+    nbrs = np.array([[1, 2, n], [3, 1, n], [4, 5, 6]], np.int32)
+    hit = np.array([[True, False, False], [False, False, False],
+                    [False, True, True]])
+    roots = np.array([1, 2, 5, 6, 7, n, n, n], np.int32)
+    got = splice_hits(jnp.asarray(nbrs), jnp.asarray(hit), jnp.asarray(roots),
+                      n=n)
+    np.testing.assert_array_equal(
+        np.asarray(got), [True, False, True, True, False, False, False,
+                          False])
+
+
+def test_run_with_a_given_index_matches_its_own():
+    """engine.run over a prebuilt index (and a subset of a bigger one)
+    answers exactly as with the index it would build itself."""
+    from repro.core.index import build_index
+    from repro.core.query import PathQuery
+
+    g = generators.community(300, n_comm=3, avg_deg=4, seed=5)
+    qs = [PathQuery(s, t, 4) for s, t, _ in
+          generators.random_queries(g, 6, k_range=(4, 4), seed=2)]
+    eng = BatchPathEngine(g, EngineConfig(min_cap=64))
+    full = build_index(eng.dg, [q.key for q in qs])
+    want = eng.run(qs[2:5])
+    got = eng.run(qs[2:5], index=full.subset([2, 3, 4]))
+    assert [path_set(r.paths) for r in got] == \
+        [path_set(r.paths) for r in want]
+    with pytest.raises(ValueError):
+        eng.run(qs[:2], index=full.subset([2, 3]))

@@ -190,10 +190,7 @@ class TestCompression:
         from repro.optim.compress import ef_compressed_psum
         import jax
         from jax.sharding import Mesh
-        try:
-            from jax import shard_map
-        except ImportError:          # jax < 0.5 keeps it in experimental
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:1]), ("pod",))
         r = np.random.default_rng(1)

@@ -1,5 +1,6 @@
-"""Unified kernel-backend API: registry semantics, fused-twin bit-equality,
-and engine-level oracle exactness under interpret-mode dispatch."""
+"""Unified kernel-backend API: registry semantics, the MS-BFS step and the
+packed sweeps against references, and engine-level oracle exactness under
+interpret-mode dispatch."""
 import warnings
 
 import jax.numpy as jnp
@@ -63,6 +64,21 @@ class TestRegistry:
             for kb in KernelBackend:
                 assert callable(dispatch(name, kb))
 
+    def test_jnp_only_ops_dispatch_their_jnp_arm(self):
+        from repro.kernels.registry import JNP_ONLY_OPS
+        assert set(JNP_ONLY_OPS) == {"msbfs_expand", "msbfs_step"}
+        for name in JNP_ONLY_OPS:
+            jnp_arm = dispatch(name, "jnp")
+            for kb in KernelBackend:
+                assert dispatch(name, kb) is jnp_arm
+
+    def test_register_op_arms_match_the_static_table(self):
+        from repro.kernels.registry import register_op
+        with pytest.raises(ValueError, match="JNP_ONLY_OPS"):
+            register_op("not_listed", jnp=len)          # missing pallas arm
+        with pytest.raises(ValueError, match="JNP_ONLY_OPS"):
+            register_op("msbfs_step", jnp=len, pallas=len, interpret=len)
+
 
 class TestEngineBackendConfig:
     def test_bogus_backend_raises_at_init(self):
@@ -107,7 +123,7 @@ class TestEngineBackendConfig:
 
 
 class TestFusedStepParity:
-    """msbfs_step: fused expand+dedup+distance-write vs its jnp twin."""
+    """msbfs_step: expand + dedup against the visited set vs plain numpy."""
 
     @given(st.integers(4, 90), st.integers(1, 6), st.integers(1, 3),
            st.integers(0, 6))
@@ -120,12 +136,11 @@ class TestFusedStepParity:
                          .astype(np.uint32)).at[-1].set(0)
         vis = jnp.asarray(r.integers(0, 2**32, (V, W), dtype=np.uint64)
                           .astype(np.uint32))
-        dist = jnp.asarray(r.integers(0, 9, (V, W * 32)).astype(np.int8))
-        hop = int(r.integers(1, 8))
-        a = msbfs_step(ell, fr, vis, dist, hop, backend="interpret")
-        b = msbfs_step(ell, fr, vis, dist, hop, backend="jnp")
-        for x, y in zip(a, b):
-            assert np.array_equal(np.asarray(x), np.asarray(y))
+        got = msbfs_step(ell, fr, vis, backend="jnp")
+        acc = np.bitwise_or.reduce(np.asarray(fr)[np.asarray(ell)], axis=1)
+        new = acc & ~np.asarray(vis)
+        for x, y in zip(got, (new, np.asarray(vis) | new)):
+            assert np.array_equal(np.asarray(x), y)
 
     def test_all_sentinel_ell(self):
         # a fully padded ELL table (empty graph row bucket) expands nothing
@@ -134,11 +149,9 @@ class TestFusedStepParity:
         ell = jnp.full((V, 4), V, jnp.int32)
         fr = jnp.ones((V + 1, W), jnp.uint32).at[-1].set(0)
         vis = jnp.zeros((V, W), jnp.uint32)
-        dist = jnp.full((V, W * 32), 9, jnp.int8)
-        nf, nv, nd = msbfs_step(ell, fr, vis, dist, 1, backend="interpret")
+        nf, nv = msbfs_step(ell, fr, vis, backend="jnp")
         assert not np.asarray(nf).any()
         assert not np.asarray(nv).any()
-        assert (np.asarray(nd) == 9).all()
 
 
 class TestSweepParity:
@@ -185,24 +198,6 @@ class TestSweepParity:
         got = msbfs_dist_ell(dg.r_ell_idx, srcs, n=dg.n, k_max=3,
                              backend="interpret")
         assert np.array_equal(np.asarray(ref), np.asarray(got))
-
-    def test_walk_counts_ell(self):
-        from repro.core.index import walk_counts, walk_counts_ell
-        from repro.core.msbfs import edge_span
-        g = _random_graph(80, 4, 11)
-        dg = DeviceGraph.build(g)
-        slack = np.full(dg.n + 1, 3, np.int8)
-        slack[-1] = -1
-        slack = jnp.asarray(slack)
-        mv = edge_span(dg.m, 1 << 22, dg.m_cap)
-        for ell, es, ed in ((dg.r_ell_idx, dg.esrc, dg.edst),
-                            (dg.ell_idx, dg.r_esrc, dg.r_edst)):
-            ref = walk_counts(es, ed, 0, slack, n=dg.n, budget=4, m_valid=mv)
-            got = walk_counts_ell(ell, 0, slack, n=dg.n, budget=4,
-                                  backend="interpret")
-            # integer-valued f32, exact below 2**24
-            assert np.array_equal(np.asarray(ref), np.asarray(got))
-
 
 class TestJoinParity:
     """Row-aligned overlap join validity vs the dense _dup_mask route, on
