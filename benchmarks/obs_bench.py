@@ -31,7 +31,7 @@ from .common import record
 # the stages the acceptance gate requires the warm exp8 trace to name
 REQUIRED_STAGES = (
     "engine.run", "cluster.queries", "detect.cluster", "cache.get",
-    "index.build", "msbfs.level", "enumerate.node", "enumerate.cluster",
+    "index.build", "enumerate.level", "enumerate.node", "enumerate.cluster",
     "join.keyed", "assemble.query", "transfer.paths",
 )
 

@@ -1,10 +1,10 @@
-"""Compile telemetry: a ``jax_log_compiles``-based retrace recorder.
+"""Compile telemetry: compile counts and spans from jax's compile events.
 
 Shape stability is the precondition for warm serving (the whole point of
 the sentinel-padded pow2 buckets in ``graph.DeviceGraph``), but XLA
 retraces are invisible unless you measure them — a drifting ``(m,)``
 shape silently turns every post-delta batch into a cold compile. This
-module turns jax's compile logging into a queryable counter so warm-
+module turns jax's compile events into a queryable counter so warm-
 compile reuse is observable in production stats and assertable in tests:
 
     recorder = enable()              # process-wide, idempotent
@@ -13,13 +13,9 @@ compile reuse is observable in production stats and assertable in tests:
     recorder.since(snap)             # {kernel_name: new compiles}
     recorder.retraces_since(snap)    # compiles of already-known kernels
 
-Mechanism: enabling flips the ``jax_log_compiles`` config flag, which
-makes jax emit one ``"Compiling jit(<name>) with global shapes..."`` log
-record per actual trace-cache miss (cached executions emit nothing); a
-logging.Handler attached to the emitting jax loggers parses those records
-into per-kernel counters. Propagation of the captured loggers is disabled
-while recording so enabling telemetry does not spray compile warnings
-over user output.
+Mechanism: ``jax.monitoring`` events at the start and end of each compile
+phase; the outermost of a kind on a thread is a tracer span (``compile.trace``
+/ ``.lower`` / ``.backend``); every backend compile counts one compile.
 
 Definitions (shared by the engine stats and the test harness):
 
@@ -28,43 +24,58 @@ Definitions (shared by the engine stats and the test harness):
   before the observation window opened — i.e. work that warm serving
   should have reused.
 
-jit caches are process-global, so the recorder is a process-global
-singleton; like the rest of the serving stack it is not thread-safe.
+jit caches are process-global, so the recorder is a process-global singleton.
 """
 from __future__ import annotations
 
-import logging
-import re
+import threading
 from collections import Counter
 from typing import Optional
 
+from jax import monitoring
+
+from ..obs import trace as obstrace
+
 __all__ = ["CompileLog", "enable", "active"]
 
-# jax emits exactly one of these per XLA compilation when the
-# jax_log_compiles flag is on, as "Compiling jit(<fn>) with global shapes
-# ..." on the pxla logger; the kernel name recorded is the bare function
-# name inside ``jit(...)``. The dispatch logger's "Finished tracing /
-# compilation ..." records do NOT match, so each compile is counted once;
-# it is captured only to keep those records off user output.
-_COMPILING_RE = re.compile(r"Compiling jit\(([^\s()]+)\) with global shapes")
-
-_JAX_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+           _BACKEND: "compile.backend"}
 
 
-class CompileLog(logging.Handler):
-    """Process-wide per-kernel compile counter (a logging.Handler)."""
+class CompileLog:
+    """Process-wide per-kernel compile counter (jax.monitoring listeners)."""
 
     def __init__(self) -> None:
-        super().__init__(level=logging.DEBUG)
         self.counts: Counter = Counter()     # kernel name -> compiles
         self._installed = False
-        self._saved_propagate: dict[str, bool] = {}
+        self._lock = threading.Lock()
+        self._local = threading.local()
 
-    # -- logging.Handler ----------------------------------------------
-    def emit(self, record: logging.LogRecord) -> None:
-        m = _COMPILING_RE.match(record.getMessage())
-        if m:
-            self.counts[m.group(1)] += 1
+    def _open(self, event: str) -> list:
+        # this thread's open phases of a kind: outermost span, then Nones
+        return self._local.__dict__.setdefault(event, [])
+
+    def _on_start(self, event: str, value, **kw) -> None:
+        if event in _PHASES:
+            stack = self._open(event)
+            stack.append(None if stack else obstrace.span(
+                _PHASES[event], fun=kw.get("fun_name")).__enter__())
+
+    def _on_end(self, event: str, start, end, **kw) -> None:
+        if event in _PHASES and self._open(event):
+            if (sp := self._open(event).pop()) is not None:
+                sp.__exit__(None, None, None)
+        if event == _BACKEND:     # fun_name "jit(f)" counts for "f"
+            name = str(kw.get("fun_name", "")).removeprefix("jit(")
+            with self._lock:
+                self.counts[name.removesuffix(")")] += 1
+
+    def _on_event(self, event: str, **kw) -> None:
+        if (event == "/jax/compilation_cache/cache_hits"
+                and self._open(_BACKEND)):
+            self._open(_BACKEND)[0].set(cache_hit=True)
 
     # -- queries -------------------------------------------------------
     @property
@@ -99,8 +110,7 @@ class CompileLog(logging.Handler):
         into ``stats`` (engine run reports, delta reports, batch logs)."""
         new = self.since(snapshot)
         stats["n_compiles"] = sum(new.values())
-        stats["n_retraces"] = sum(c for name, c in new.items()
-                                  if snapshot.get(name, 0) > 0)
+        stats["n_retraces"] = self.retraces_since(snapshot)
         stats["compiled_kernels"] = new
         return stats
 
@@ -108,29 +118,18 @@ class CompileLog(logging.Handler):
     def install(self) -> "CompileLog":
         if self._installed:
             return self
-        import jax
-
-        for name in _JAX_LOGGERS:
-            logger = logging.getLogger(name)
-            self._saved_propagate[name] = logger.propagate
-            logger.addHandler(self)
-            logger.propagate = False     # keep compile spam off user output
-            if logger.level > logging.WARNING or logger.level == 0:
-                logger.setLevel(logging.WARNING)
-        jax.config.update("jax_log_compiles", True)
+        monitoring.register_scalar_listener(self._on_start)
+        monitoring.register_event_time_span_listener(self._on_end)
+        monitoring.register_event_listener(self._on_event)
         self._installed = True
         return self
 
     def uninstall(self) -> None:
         if not self._installed:
             return
-        import jax
-
-        jax.config.update("jax_log_compiles", False)
-        for name in _JAX_LOGGERS:
-            logger = logging.getLogger(name)
-            logger.removeHandler(self)
-            logger.propagate = self._saved_propagate.get(name, True)
+        monitoring.unregister_scalar_listener(self._on_start)
+        monitoring.unregister_event_time_span_listener(self._on_end)
+        monitoring.unregister_event_listener(self._on_event)
         self._installed = False
 
 

@@ -22,6 +22,7 @@ import warnings
 import weakref
 from typing import Callable, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -189,6 +190,17 @@ class BatchPathEngine:
             fence=self.cfg.trace_fence,
             annotate=self.cfg.trace_annotations) if self.cfg.trace \
             else obstrace.tracer()
+        # search-node counters, bound once: the hot path pays one add each
+        reg = obsmetrics.registry()
+        self._n_nodes = reg.counter("engine_nodes_total")
+        self._node_syncs = reg.counter("engine_host_syncs_total",
+                                       stage="node")
+        self._asm_syncs = reg.counter("engine_host_syncs_total",
+                                      stage="assemble")
+        self._node_retries = reg.counter("engine_retries_total", kind="node")
+        self._join_retries = reg.counter("engine_retries_total", kind="join")
+        self._fetched: weakref.WeakValueDictionary = \
+            weakref.WeakValueDictionary()     # id -> device value read
 
     def set_graph(self, graph: Graph) -> None:
         """Swap the graph wholesale: rebuild device views and drop every
@@ -402,17 +414,42 @@ class BatchPathEngine:
         built for its similarity); None builds one. Ignored by PATHENUM,
         which indexes each query alone.
 
+        The report stats count this run's search nodes (``n_nodes``),
+        their device→host reads (``n_node_syncs``), those of answer
+        assembly (``n_assemble_syncs``) and the overflow re-runs of nodes
+        and joins (``n_retries``).
+
         With ``EngineConfig.log_compiles`` the report stats carry this
         run's compile-telemetry window: ``n_compiles`` (trace-cache
         misses), ``n_retraces`` (misses on kernels that were already warm
         — zero on a shape-stable serving path) and ``compiled_kernels``.
         """
+        before = self._search_counts()
         if self.compile_log is None:
-            return self._run_impl(queries, planner, clusters, index)
-        snap = self.compile_log.snapshot()
-        report = self._run_impl(queries, planner, clusters, index)
-        self.compile_log.annotate(report.stats, snap)
+            report = self._run_impl(queries, planner, clusters, index)
+        else:
+            snap = self.compile_log.snapshot()
+            report = self._run_impl(queries, planner, clusters, index)
+            self.compile_log.annotate(report.stats, snap)
+        report.stats.update(
+            (key, int(b - a)) for key, a, b in zip(
+                ("n_nodes", "n_node_syncs", "n_assemble_syncs", "n_retries"),
+                before, self._search_counts()))
         return report
+
+    def _host(self, x, syncs: obsmetrics.Counter) -> np.ndarray:
+        """``x`` on the host. The first read of a device value is a
+        device→host round-trip, counted on ``syncs``; JAX keeps the host
+        copy it fetched, so reading the value again costs none."""
+        if isinstance(x, jax.Array) and id(x) not in self._fetched:
+            self._fetched[id(x)] = x
+            syncs.inc()
+        return np.asarray(x)
+
+    def _search_counts(self) -> tuple:
+        return (self._n_nodes.value, self._node_syncs.value,
+                self._asm_syncs.value,
+                self._node_retries.value + self._join_retries.value)
 
     def _run_impl(self, queries: Sequence[QueryLike],
                   planner: Planner | str,
@@ -434,16 +471,17 @@ class BatchPathEngine:
                 report = self._run_pathenum(qs, stats)
             else:
                 keys = tuple(q.key for q in qs)
-                with self.obs.span("index.build", n_queries=len(qs),
-                                   given=index is not None) as sidx:
-                    if index is None:
+                stats["t_build_index"] = 0.0
+                if index is None:
+                    with self.obs.span("index.build",
+                                       n_queries=len(qs)) as sidx:
                         index = build_index(self._kernel_dg(), keys,
                                             self.cfg.edge_chunk,
                                             backend=self._kb)
-                    elif index.queries != keys:
-                        raise ValueError("index was built for other queries")
-                    index.dist_s.block_until_ready()
-                stats["t_build_index"] = sidx.duration
+                        index.dist_s.block_until_ready()
+                    stats["t_build_index"] = sidx.duration
+                elif index.queries != keys:
+                    raise ValueError("index was built for other queries")
                 if planner is Planner.AUTO:
                     report = self._run_auto(qs, index, plus, stats,
                                             clusters)
@@ -887,10 +925,13 @@ class BatchPathEngine:
     # ------------------------------------------------------------------
     def _run_node(self, reverse: bool, source: int, budget: int, slack,
                   children, stop_vertex: int = -2):
+        self._n_nodes.inc()
         with self.obs.span("enumerate.node", src=source, budget=budget,
                            reverse=reverse):
             caps = self._plan_caps(reverse, source, budget, slack)
-            for _ in range(8):
+            for attempt in range(8):
+                if attempt:
+                    self._node_retries.inc()
                 out = self._run_node_once(reverse, source, budget, slack,
                                           children, stop_vertex, caps)
                 if out is not None:
@@ -923,14 +964,14 @@ class BatchPathEngine:
         pools: list[list[PathSet]] = [[] for _ in range(budget + 1)]
         frontier = singleton(source, width)
         pools[0].append(frontier)
-        obs = self.obs
+        obs, syncs = self.obs, self._node_syncs
         for lvl in range(budget):
-            if int(frontier.count) == 0:
+            if int(self._host(frontier.count, syncs)) == 0:
                 break
-            # per-level MS-BFS superstep: the overflow read is the level's
+            # per-level expand superstep: the overflow read is the level's
             # host sync point, so the span charges the level's device work
             # to itself even without fencing
-            with obs.span("msbfs.level", level=lvl,
+            with obs.span("enumerate.level", level=lvl,
                           reverse=reverse) as sl:
                 out = expand_level(frontier.verts, frontier.count, ell_idx,
                                    prune_tbl, stop,
@@ -938,11 +979,11 @@ class BatchPathEngine:
                                    out_cap=caps[lvl + 1],
                                    backend=self._kb)
                 sl.fence(out.frontier.verts)
-                overflow = bool(out.frontier.overflow)
+                overflow = bool(self._host(out.frontier.overflow, syncs))
             if overflow:
                 return None
-            hit = (np.asarray(splice_hits(out.nbrs, out.splice_hit, csrcs,
-                                           n=n))
+            hit = (self._host(splice_hits(out.nbrs, out.splice_hit, csrcs,
+                                          n=n), syncs)
                    if children else ())
             for (csrc, cb, clevels), any_hit in zip(children, hit):
                 if not any_hit:
@@ -951,11 +992,13 @@ class BatchPathEngine:
                     rmask = (out.splice_hit & (out.nbrs == csrc)).any(axis=1)
                     prefixes = extract_rows(frontier.verts, rmask,
                                             out_cap=frontier.cap)
-                    if int(prefixes.count) == 0:
+                    n_pre = int(self._host(prefixes.count, syncs))
+                    if n_pre == 0:
                         continue
                     for lam in range(0, min(cb, budget - lvl - 1) + 1):
                         cl = clevels[lam]
-                        if int(cl.count) == 0:
+                        n_cl = int(self._host(cl.count, syncs))
+                        if n_cl == 0:
                             continue
                         res = self._retry_join(
                             lambda cap: cross_join(
@@ -964,7 +1007,7 @@ class BatchPathEngine:
                                 p_col=lvl, c_col=lam, out_cap=cap,
                                 out_width=width,
                                 backend=self._kb),
-                            est=int(prefixes.count) * int(cl.count))
+                            est=n_pre * n_cl, syncs=syncs)
                         pools[lvl + 1 + lam].append(res)
             frontier = out.frontier
             pools[lvl + 1].append(out.frontier)
@@ -974,28 +1017,32 @@ class BatchPathEngine:
     def _shrink(self, ps: PathSet) -> PathSet:
         """Slice a packed PathSet down to a tight capacity bucket — keeps
         the downstream join/sort jit cache to a handful of shapes."""
-        tight = _bucket(int(ps.count), self.cfg.min_cap)
+        tight = _bucket(int(self._host(ps.count, self._node_syncs)),
+                        self.cfg.min_cap)
         if tight >= ps.cap:
             return ps
         return PathSet(ps.verts[:tight], ps.count, ps.overflow)
 
-    def _retry_capacity(self, fn, est: int):
-        """Run ``fn(cap) -> (result, overflow)`` with cap-doubling retry."""
+    def _retry_capacity(self, fn, est: int, syncs: obsmetrics.Counter):
+        """Run ``fn(cap) -> (result, overflow)`` with cap-doubling retry;
+        each overflow read counts on ``syncs``."""
         cap = _bucket(min(max(est, self.cfg.min_cap), self.cfg.join_cap),
                       self.cfg.min_cap)
         while True:
             res, overflow = fn(cap)
-            if not bool(overflow):
+            if not bool(self._host(overflow, syncs)):
                 return res
             if cap >= self.cfg.hard_cap:
                 raise EngineOverflow("join exceeds hard_cap")
             cap = min(cap * 4, self.cfg.hard_cap)
+            self._join_retries.inc()
 
-    def _retry_join(self, fn, est: int) -> PathSet:
+    def _retry_join(self, fn, est: int, syncs: obsmetrics.Counter
+                    ) -> PathSet:
         def attempt(cap):
             ps = fn(cap)
             return ps, ps.overflow
-        return self._retry_capacity(attempt, est)
+        return self._retry_capacity(attempt, est, syncs)
 
     # ------------------------------------------------------------------
     # final ⊕ assembly (exact split, each result exactly once), dispatched
@@ -1012,7 +1059,8 @@ class BatchPathEngine:
         if q.output is Output.PATHS:
             ps = self._assemble(fwd_levels, a, bwd, b, q.t, q.k,
                                 limit=q.limit)
-            stats["n_rows_assembled"] += int(ps.count)
+            stats["n_rows_assembled"] += int(self._host(ps.count,
+                                                   self._asm_syncs))
             return PathsStore(ps)
         limit = 1 if q.output is Output.EXISTS else q.limit
         return self._assemble_count(fwd_levels, a, bwd, b, q.t, q.k,
@@ -1032,21 +1080,24 @@ class BatchPathEngine:
         reached — a limit already met by forward completions skips the
         backward enumeration entirely (basic planners)."""
         width = k + 1
+        syncs = self._asm_syncs
         outs = []
         found = 0
         for lvl in range(1, min(a, len(fwd_levels) - 1) + 1):
             if limit is not None and found >= limit:
                 break
             ps = fwd_levels[lvl]
-            if int(ps.count) == 0:
+            if int(self._host(ps.count, syncs)) == 0:
                 continue
             sel = select_ending_at(ps.verts, ps.count, jnp.int32(t),
                                    col=lvl, out_cap=ps.cap)
-            if int(sel.count):
+            n_sel = int(self._host(sel.count, syncs))
+            if n_sel:
                 outs.append(_pad_width(sel, width))
-                found += int(sel.count)
+                found += n_sel
         if (not (limit is not None and found >= limit) and b >= 1
-                and len(fwd_levels) > a and int(fwd_levels[a].count) > 0):
+                and len(fwd_levels) > a
+                and int(self._host(fwd_levels[a].count, syncs)) > 0):
             bwd_levels = bwd()
             fa = fwd_levels[a]
             sa = sort_by_last(fa.verts, fa.count, col=a)
@@ -1054,7 +1105,8 @@ class BatchPathEngine:
                 if limit is not None and found >= limit:
                     break
                 bs = bwd_levels[lam]
-                if int(bs.count) == 0:
+                n_bs = int(self._host(bs.count, syncs))
+                if n_bs == 0:
                     continue
                 with self.obs.span("join.keyed", lam=lam):
                     res = self._retry_join(
@@ -1062,10 +1114,12 @@ class BatchPathEngine:
                                                a_col=a, b_col=lam,
                                                out_cap=cap, out_width=width,
                                                backend=self._kb),
-                        est=max(int(fa.count), int(bs.count)))
-                if int(res.count):
+                        est=max(int(self._host(fa.count, syncs)), n_bs),
+                        syncs=syncs)
+                n_res = int(self._host(res.count, syncs))
+                if n_res:
                     outs.append(res)
-                    found += int(res.count)
+                    found += n_res
         if not outs:
             return empty(1, width)
         out = concat(outs)
@@ -1079,22 +1133,26 @@ class BatchPathEngine:
         """Exact ⊕ count without assembling paths: forward completions are
         mask reductions, the bidirectional part a counting join. ``limit``
         early-terminates (1 for exists-only) and clamps the total."""
+        syncs = self._asm_syncs
         total = 0
         for lvl in range(1, min(a, len(fwd_levels) - 1) + 1):
             ps = fwd_levels[lvl]
-            if int(ps.count) == 0:
+            if int(self._host(ps.count, syncs)) == 0:
                 continue
-            total += int(count_ending_at(ps.verts, ps.count, jnp.int32(t),
-                                         col=lvl))
+            total += int(self._host(count_ending_at(ps.verts, ps.count,
+                                               jnp.int32(t), col=lvl),
+                               syncs))
             if limit is not None and total >= limit:
                 return limit
-        if b >= 1 and len(fwd_levels) > a and int(fwd_levels[a].count) > 0:
+        if (b >= 1 and len(fwd_levels) > a
+                and int(self._host(fwd_levels[a].count, syncs)) > 0):
             bwd_levels = bwd()
             fa = fwd_levels[a]
             sa = sort_by_last(fa.verts, fa.count, col=a)
             for lam in range(1, min(b, len(bwd_levels) - 1) + 1):
                 bs = bwd_levels[lam]
-                if int(bs.count) == 0:
+                n_bs = int(self._host(bs.count, syncs))
+                if n_bs == 0:
                     continue
                 with self.obs.span("join.keyed", lam=lam, count=True):
                     total += self._retry_count(
@@ -1102,13 +1160,14 @@ class BatchPathEngine:
                                                      a_col=a, b_col=lam,
                                                      pair_cap=cap,
                                                      backend=self._kb),
-                        est=max(int(fa.count), int(bs.count)))
+                        est=max(int(self._host(fa.count, syncs)), n_bs))
                 if limit is not None and total >= limit:
                     return limit
         return total if limit is None else min(total, limit)
 
     def _retry_count(self, fn, est: int) -> int:
-        return int(self._retry_capacity(fn, est))
+        syncs = self._asm_syncs
+        return int(self._host(self._retry_capacity(fn, est, syncs), syncs))
 
     # ------------------------------------------------------------------
     # helpers
@@ -1175,7 +1234,8 @@ class BatchPathEngine:
     def _plan_caps(self, reverse: bool, source: int, budget: int, slack):
         if not self.cfg.plan_caps:
             return [self.cfg.min_cap] * (budget + 1)
-        tot = self._walk_counts(reverse, source, slack, budget)
+        tot = self._walk_counts(reverse, source,
+                                self._host(slack, self._node_syncs), budget)
         caps = [_bucket(min(int(min(t, 2**31)), self.cfg.max_cap),
                         self.cfg.min_cap) for t in tot]
         return caps

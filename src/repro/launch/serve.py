@@ -504,14 +504,19 @@ class StreamingServer:
             steals_before = self.sched.steals
             failovers_before = self.sched.failovers
             requeued_before = self.sched.requeued
-            with self.engine.obs.span("serve.assemble",
-                                      n_queries=len(batch)) as sasm:
+            obs = self.engine.obs
+            with obs.span("serve.assemble", n_queries=len(batch)) as sasm:
                 # one index per micro-batch: it feeds the similarity here
-                # and every cluster's engine.run below
-                index = build_index(self.engine.dg, [q.key for q in queries],
-                                    backend=self.engine.kernel_backend.value)
-                mu = similarity_matrix(
-                    index, backend=self.engine.kernel_backend.value)
+                # and every cluster's engine.run below; a fenced trace
+                # charges the sweep to index.build
+                with obs.span("index.build", n_queries=len(batch)) as sidx:
+                    index = build_index(
+                        self.engine.dg, [q.key for q in queries],
+                        backend=self.engine.kernel_backend.value)
+                    sidx.fence((index.dist_s, index.dist_t))
+                with obs.span("cluster.similarity"):
+                    mu = similarity_matrix(
+                        index, backend=self.engine.kernel_backend.value)
                 bias = warm_cluster_bias(self.engine, queries,
                                          self.warm_bias_eps)
                 # balance_clusters must act HERE, not just inside
@@ -533,6 +538,8 @@ class StreamingServer:
             agg = {"n_psi_nodes": 0, "n_materialized": 0,
                    "n_cache_hits": 0, "n_cache_misses": 0,
                    "n_compiles": 0, "n_retraces": 0,
+                   "n_nodes": 0, "n_node_syncs": 0, "n_assemble_syncs": 0,
+                   "n_retries": 0,
                    "routed_green": 0, "routed_yellow": 0, "routed_red": 0}
             per_device = None
             executor = self.engine.executor

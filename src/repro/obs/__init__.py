@@ -13,7 +13,7 @@ Three layers, increasingly optional:
   a plain-text exposition dump.
 * :mod:`repro.obs.jaxprof` — opt-in ``jax.profiler`` bridge: span
   annotations on the device timeline, ``start_trace``/``stop_trace``
-  capture, device-memory gauges.
+  capture.
 
 ``python -m repro.obs summarize <trace.json>`` aggregates a saved trace;
 see ``docs/observability.md`` for the span taxonomy and metric names.

@@ -141,8 +141,8 @@ class Tracer:
         self.enabled = enabled
         self.fence = fence
         # annotator: name -> context manager entered for the span's
-        # lifetime (jaxprof.attach installs jax.profiler.TraceAnnotation
-        # so host spans also appear on the device timeline)
+        # lifetime (enable(annotate=True) installs jax.profiler's
+        # TraceAnnotation so host spans also appear on the device timeline)
         self.annotator = annotator
         self._buf: deque = deque(maxlen=int(capacity))
         self._local = threading.local()

@@ -4,6 +4,7 @@ tracing off must not change results or warm retraces, and the t_* stats
 must stay derived views over spans either way."""
 import json
 import threading
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -119,7 +120,7 @@ class TestSpans:
     def test_chrome_trace_round_trip(self, tmp_path):
         tr = obstrace.Tracer(enabled=True)
         with tr.span("engine.run", n_queries=3):
-            with tr.span("msbfs.level", level=0):
+            with tr.span("enumerate.level", level=0):
                 pass
             with tr.span("join.keyed", lam=2):
                 pass
@@ -129,12 +130,12 @@ class TestSpans:
         assert loaded == json.loads(json.dumps(doc))
         assert loaded["displayTimeUnit"] == "ms"
         assert obstrace.stage_names(loaded) == \
-            {"engine.run", "msbfs.level", "join.keyed"}
+            {"engine.run", "enumerate.level", "join.keyed"}
         ev = {e["name"]: e for e in loaded["traceEvents"]
               if e.get("ph") == "X"}
         assert ev["engine.run"]["args"] == {"n_queries": 3, "depth": 0}
-        assert ev["msbfs.level"]["args"]["depth"] == 1
-        assert ev["msbfs.level"]["ts"] >= ev["engine.run"]["ts"]
+        assert ev["enumerate.level"]["args"]["depth"] == 1
+        assert ev["enumerate.level"]["ts"] >= ev["engine.run"]["ts"]
         # metadata thread_name event present
         assert any(e.get("ph") == "M" for e in loaded["traceEvents"])
 
@@ -142,13 +143,13 @@ class TestSpans:
         tr = obstrace.Tracer(enabled=True)
         with tr.span("engine.run"):
             for lv in range(3):
-                with tr.span("msbfs.level", level=lv):
+                with tr.span("enumerate.level", level=lv):
                     sum(range(20000))
         doc = tr.to_chrome()
         rows = {r["name"]: r for r in obstrace.summarize(doc)}
-        assert rows["msbfs.level"]["count"] == 3
+        assert rows["enumerate.level"]["count"] == 3
         assert rows["engine.run"]["total_ms"] >= \
-            rows["msbfs.level"]["total_ms"] * 0.9
+            rows["enumerate.level"]["total_ms"] * 0.9
         cov = obstrace.coverage(doc, root="engine.run")
         assert 0.5 <= cov <= 1.0
 
@@ -250,7 +251,7 @@ class TestEngineIntegration:
         # cluster splices shared-prefix children (the exp8 obs benchmark
         # pins the fuller taxonomy on a sharing-heavy workload)
         for stage in ("engine.run", "cluster.queries", "detect.cluster",
-                      "cache.get", "index.build", "msbfs.level",
+                      "cache.get", "index.build", "enumerate.level",
                       "enumerate.node", "enumerate.cluster",
                       "join.keyed", "assemble.query"):
             assert stage in names, stage
@@ -390,7 +391,7 @@ class TestCli:
     def _write_trace(self, tmp_path):
         tr = obstrace.Tracer(enabled=True)
         with tr.span("engine.run"):
-            with tr.span("msbfs.level", level=0):
+            with tr.span("enumerate.level", level=0):
                 pass
         p = tmp_path / "t.json"
         tr.export(p)
@@ -401,19 +402,145 @@ class TestCli:
         p = self._write_trace(tmp_path)
         assert main(["summarize", str(p)]) == 0
         out = capsys.readouterr().out
-        assert "msbfs.level" in out and "coverage" in out
+        assert "enumerate.level" in out and "coverage" in out
 
     def test_export_filter(self, tmp_path):
         from repro.obs.__main__ import main
         p = self._write_trace(tmp_path)
         out = tmp_path / "f.json"
         assert main(["export", str(p), "-o", str(out),
-                     "--filter", "msbfs."]) == 0
+                     "--filter", "enumerate."]) == 0
         doc = obstrace.load(out)
-        assert obstrace.stage_names(doc) == {"msbfs.level"}
+        assert obstrace.stage_names(doc) == {"enumerate.level"}
 
     def test_summarize_empty_trace_fails(self, tmp_path):
         from repro.obs.__main__ import main
         p = tmp_path / "empty.json"
         p.write_text('{"traceEvents": []}')
         assert main(["summarize", str(p)]) == 1
+
+
+# ----------------------------------------------------------------------
+# compile spans and counts from jax.monitoring (core.compilelog)
+# ----------------------------------------------------------------------
+class TestCompileEvents:
+    def test_fresh_jit_records_three_phase_spans_under_caller(self):
+        import jax
+        import jax.numpy as jnp
+        from repro.core import compilelog
+
+        log = compilelog.enable()
+        x = jax.block_until_ready(jnp.arange(5))
+        tr = obstrace.enable().reset()
+
+        @jax.jit
+        def fresh_fn(v):          # a new function: a cold jit cache
+            return v * 3 + 1
+
+        snap = log.snapshot()
+        with tr.span("caller") as caller:
+            jax.block_until_ready(fresh_fn(x))
+        comp = [s for s in tr.spans() if s.name.startswith("compile.")]
+        assert sorted(s.name for s in comp) == \
+            ["compile.backend", "compile.lower", "compile.trace"]
+        for s in comp:
+            assert s.depth == caller.depth + 1, s.name
+            assert caller.t0 <= s.t0 <= s.t1 <= caller.t1, s.name
+        assert log.since(snap) == {"fresh_fn": 1}
+
+        n = len(tr.spans())
+        with tr.span("caller"):
+            jax.block_until_ready(fresh_fn(x))     # warm: no compile
+        assert not [s for s in tr.spans()[n:]
+                    if s.name.startswith("compile.")]
+        assert log.since(snap) == {"fresh_fn": 1}
+
+    def test_count_matches_jax_compile_log_over_serving(self):
+        """With ``jax_log_compiles`` on for this test only, the listener
+        counts exactly the ``Compiling ... with global shapes`` records
+        over the recompile harness's different-query batches, served."""
+        import logging
+        import re
+
+        import jax
+        from repro.core import compilelog
+
+        pattern = re.compile(r"Compiling jit\(([^\s()]+)\) with global shapes")
+        logged = []
+
+        class Grab(logging.Handler):
+            def emit(self, record):
+                m = pattern.match(record.getMessage())
+                if m:
+                    logged.append(m.group(1))
+
+        n = 56                    # shapes no other test here compiles
+        log = compilelog.enable()
+        sess = PathSession(circulant(n), EngineConfig(min_cap=256))
+        logger = logging.getLogger("jax._src.interpreters.pxla")
+        grab = Grab(level=logging.DEBUG)
+        logger.addHandler(grab)
+        jax.config.update("jax_log_compiles", True)
+        try:
+            snap = log.snapshot()
+            for i in range(3):
+                for j in range(6):
+                    sess.submit((8 * j + i, (8 * j + i + 3) % n, 3))
+                sess.results()
+            got = log.since(snap)
+        finally:
+            jax.config.update("jax_log_compiles", False)
+            logger.removeHandler(grab)
+        assert logged, "the cold batch must compile"
+        assert sum(got.values()) == len(logged)
+        assert got == dict(Counter(logged))
+
+
+# ----------------------------------------------------------------------
+# host-sync and retry counters per search node
+# ----------------------------------------------------------------------
+class TestSearchCounters:
+    KEYS = ("n_nodes", "n_node_syncs", "n_assemble_syncs", "n_retries")
+
+    def test_host_read_counts_one_round_trip_per_value(self):
+        import jax.numpy as jnp
+
+        eng, c = _engine(), obsmetrics.Counter()
+        x = jnp.arange(4) + 1               # a device value
+        assert eng._host(x, c).tolist() == [1, 2, 3, 4] and c.value == 1
+        eng._host(x, c)                     # JAX keeps the host copy
+        eng._host(np.arange(3), c)          # already on the host
+        assert c.value == 1
+        eng._host(x + 1, c)                 # a new device value
+        assert c.value == 2
+
+    def test_run_and_batch_log_report_node_counters(self):
+        eng = _engine(cache_bytes=0)
+        reg = obsmetrics.registry()
+        snap = reg.snapshot()
+        st = eng.run(QS).stats
+        assert st["n_nodes"] > 0
+        assert st["n_node_syncs"] >= st["n_nodes"]
+        assert st["n_assemble_syncs"] > 0
+        win = reg.since(snap)
+        assert win[("engine_nodes_total", ())] == st["n_nodes"]
+        assert win[("engine_host_syncs_total", (("stage", "node"),))] == \
+            st["n_node_syncs"]
+
+        sess = PathSession(circulant(), EngineConfig(min_cap=256))
+        for q in QS:
+            sess.submit(q)
+        sess.results()
+        entry = sess.batch_log[-1]
+        assert all(k in entry for k in self.KEYS)
+        assert entry["n_nodes"] > 0
+        assert entry["n_node_syncs"] >= entry["n_nodes"]
+
+    def test_tiny_caps_force_counted_retries(self):
+        want = _engine(cache_bytes=0).run(QS)
+        tiny = _engine(cache_bytes=0, min_cap=1, join_cap=1,
+                       plan_caps=False)
+        r = tiny.run(QS)
+        assert r.stats["n_retries"] > 0
+        for qi in range(len(QS)):
+            assert path_set(r[qi].paths) == path_set(want[qi].paths)
