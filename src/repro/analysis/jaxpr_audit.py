@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+from functools import partial
 from pathlib import Path
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
@@ -91,25 +92,37 @@ def _mk_msbfs_set_dist(backend: str, k: int):
             (esrc, edst, seed))
 
 
-def _mk_msbfs_dist_ell(backend: str, k: int):
+def _sweep_table(sliced: bool, n: int = 16, D: int = 4):
+    """A padded (n+1, D) ELL, or a sliced ELL of the same n rows: four
+    tables of widths 4, 2, 1 and 0 (the last a degree-0 tail)."""
+    import jax.numpy as jnp
+    from ..core.graph import SlicedEll
+    if not sliced:
+        return jnp.full((n + 1, D), n, jnp.int32)
+    rows = (2, 4, 6, n - 12)
+    return SlicedEll(jnp.arange(n, dtype=jnp.int32),
+                     jnp.arange(n, dtype=jnp.int32),
+                     tuple(jnp.full((r, w), n, jnp.int32)
+                           for r, w in zip(rows, (D, 2, 1, 0))))
+
+
+def _mk_msbfs_dist_ell(backend: str, k: int, sliced: bool = False):
     import jax.numpy as jnp
     from ..core.msbfs import msbfs_dist_ell
-    n, D, S = 16, 4, 4
-    ell = jnp.full((n + 1, D), n, jnp.int32)
+    n, S = 16, 4
     srcs = jnp.zeros((S,), jnp.int32)
     return (lambda a, b: msbfs_dist_ell(a, b, n=n, k_max=k, backend=backend),
-            (ell, srcs))
+            (_sweep_table(sliced, n), srcs))
 
 
-def _mk_msbfs_set_dist_ell(backend: str, k: int):
+def _mk_msbfs_set_dist_ell(backend: str, k: int, sliced: bool = False):
     import jax.numpy as jnp
     from ..core.msbfs import msbfs_set_dist_ell
-    n, D = 16, 4
-    ell = jnp.full((n + 1, D), n, jnp.int32)
+    n = 16
     seed = jnp.zeros((n + 1,), jnp.int8)
     return (lambda a, b: msbfs_set_dist_ell(a, b, n=n, k_max=k,
                                             backend=backend),
-            (ell, seed))
+            (_sweep_table(sliced, n), seed))
 
 
 def _mk_expand_level(backend: str, k: int):
@@ -165,6 +178,12 @@ MANIFEST: Tuple[HotFn, ...] = (
     HotFn("msbfs_set_dist", ("jnp",), _mk_msbfs_set_dist),
     HotFn("msbfs_dist_ell", ("jnp", "interpret"), _mk_msbfs_dist_ell),
     HotFn("msbfs_set_dist_ell", ("jnp", "interpret"), _mk_msbfs_set_dist_ell),
+    # the sliced ELL a full DeviceGraph.build gives the index sweep (the
+    # two entries above: the padded ELL of a delta-patched graph)
+    HotFn("msbfs_dist_ell_sliced", ("jnp", "interpret"),
+          partial(_mk_msbfs_dist_ell, sliced=True)),
+    HotFn("msbfs_set_dist_ell_sliced", ("jnp", "interpret"),
+          partial(_mk_msbfs_set_dist_ell, sliced=True)),
     HotFn("expand_level", ("jnp", "interpret"), _mk_expand_level,
           leveled=False),
     HotFn("keyed_join", ("jnp", "interpret"), _mk_keyed_join, leveled=False),

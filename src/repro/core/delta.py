@@ -270,10 +270,13 @@ def update_device_graph(dg: DeviceGraph, applied: AppliedDelta,
     and the padded ELL matrices — the big (n, cap) buffers the kernels
     read — are updated by scattering only the touched rows. In-bucket
     churn therefore changes no traced shape and re-uses every warm
-    compile. Falls back to a full ``DeviceGraph.build`` when a touched
-    row outgrows the current ELL capacity (the ELL must stay spill-free
-    for enumeration); the rebuild re-buckets and is the one mutation that
-    may retrace — at most once per bucket crossing.
+    compile. The sliced ELL of the index sweep is dropped (the sweep
+    takes the padded ELL from then on; the first sweep after the drop
+    compiles that table's program once). Falls back to a full
+    ``DeviceGraph.build`` when a touched row outgrows the current ELL
+    capacity (the ELL must stay spill-free for enumeration); the rebuild
+    re-buckets, restores the sliced ELL and is the one mutation that may
+    retrace — at most once per bucket crossing.
     """
     import jax.numpy as jnp
 
@@ -306,12 +309,16 @@ def update_device_graph(dg: DeviceGraph, applied: AppliedDelta,
     cap = dg.m_cap if g2.m <= dg.m_cap else pow2_ceil(g2.m)
     esrc, edst = pad_edge_list(*g2.edges_by_dst, g2.n, cap)
     r_esrc, r_edst = pad_edge_list(*g2.r_edges_by_dst, g2.n, cap)
+    # the sliced ELL's shapes follow every row's degree, so it is dropped
+    # rather than patched: the index sweep falls back to the padded ELL
+    # (fixed shapes under in-bucket churn) until the next full build
     return dataclasses.replace(
         dg, m=g2.m,
         esrc=jnp.asarray(esrc), edst=jnp.asarray(edst),
         ell_idx=ell_idx, ell_mask=ell_mask,
         r_esrc=jnp.asarray(r_esrc), r_edst=jnp.asarray(r_edst),
-        r_ell_idx=r_ell_idx, r_ell_mask=r_ell_mask), True
+        r_ell_idx=r_ell_idx, r_ell_mask=r_ell_mask,
+        ell_sliced=None, r_ell_sliced=None), True
 
 
 def host_set_dist(g_old: Graph, applied: AppliedDelta, k_max: int,

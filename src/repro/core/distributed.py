@@ -52,7 +52,8 @@ __all__ = ["shard_edges", "distributed_graph", "shard_graph_edges",
 
 # every device-resident array field of a DeviceGraph (the placement unit)
 _DG_ARRAYS = ("esrc", "edst", "ell_idx", "ell_mask",
-              "r_esrc", "r_edst", "r_ell_idx", "r_ell_mask")
+              "r_esrc", "r_edst", "r_ell_idx", "r_ell_mask",
+              "ell_sliced", "r_ell_sliced")   # pytrees of arrays, or None
 
 
 # ----------------------------------------------------------------------

@@ -35,7 +35,7 @@ from .delta import (AppliedDelta, GraphDelta, apply_delta as _merge_delta,
 from .graph import DeviceGraph, Graph
 from .index import (QueryIndex, build_index, column_reach, slack_vector,
                     walk_counts)
-from .msbfs import (K_MAX_INT8, edge_span, msbfs_set_dist,
+from .msbfs import (K_MAX_INT8, count_sweep, edge_span, msbfs_set_dist,
                     msbfs_set_dist_ell)
 from ..kernels.registry import resolve_backend
 from .pathset import PathSet, concat, empty, singleton
@@ -128,7 +128,8 @@ def _sync_device_graph(dg: DeviceGraph) -> None:
     import jax
 
     jax.block_until_ready((dg.esrc, dg.edst, dg.ell_idx, dg.ell_mask,
-                           dg.r_esrc, dg.r_edst, dg.r_ell_idx, dg.r_ell_mask))
+                           dg.r_esrc, dg.r_edst, dg.r_ell_idx, dg.r_ell_mask,
+                           dg.ell_sliced, dg.r_ell_sliced))
 
 
 def _bucket(x: int, min_cap: int = 256) -> int:
@@ -366,9 +367,11 @@ class BatchPathEngine:
             # fused bit-packed sweep: "from" distances relax over G's
             # in-neighbors (r_ell), "to" over G_r's (ell) — bit-equal to
             # the segment path below
-            for name, ell in (("from", kdg.r_ell_idx), ("to", kdg.ell_idx)):
-                d = msbfs_set_dist_ell(ell, seed, n=self.g.n, k_max=k_max,
+            for name, reverse in (("from", True), ("to", False)):
+                table = kdg.sweep_table(reverse)
+                d = msbfs_set_dist_ell(table, seed, n=self.g.n, k_max=k_max,
                                        backend=self._kb)
+                count_sweep(table, self.g.n, kdg.m, k_max)
                 dists[name] = np.asarray(d)
             return dists
         m_valid = edge_span(kdg.m, self.cfg.edge_chunk, kdg.m_cap)

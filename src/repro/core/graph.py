@@ -9,9 +9,14 @@ jit-compilable:
   * edge list      -- (src, dst) sorted by dst; drives segment-reduce hops.
   * padded ELL     -- (V, max_deg_cap) neighbor matrix padded with the
                       sentinel row ``V`` (frontier tables carry one extra
-                      zero row); drives the Pallas kernels and the
-                      enumeration gather. Vertices with deg > cap spill to a
-                      COO remainder (power-law safety valve).
+                      zero row); drives the enumeration gather and the
+                      index sweep of a mutated graph. Vertices with
+                      deg > cap spill to a COO remainder (power-law safety
+                      valve).
+  * sliced ELL     -- rows sorted by degree and cut into at most
+                      ``MAX_SLICES`` tables, each as wide as its own rows
+                      need (:class:`SlicedEll`); drives the packed index
+                      sweep, which then gathers about one row per arc.
 
 Shape stability under mutation: every *device* view is quantized to a
 power-of-two bucket so incremental edge churn (``delta.apply_delta``)
@@ -28,16 +33,19 @@ from __future__ import annotations
 
 import dataclasses
 from functools import cached_property
-from typing import Optional, TYPE_CHECKING
+from typing import NamedTuple, Optional, TYPE_CHECKING
 
 import numpy as np
 
 if TYPE_CHECKING:   # the "jax.Array" annotations below; jax itself is
     import jax      # imported lazily so host-only use never inits a device
 
-__all__ = ["Graph", "DeviceGraph", "EllView", "pow2_ceil", "pad_edge_list"]
+__all__ = ["Graph", "DeviceGraph", "EllView", "SlicedEll", "sliced_ell",
+           "slice_bounds", "pow2_ceil", "pad_edge_list", "MAX_SLICES"]
 
 SENTINEL = -1
+# most tables a sliced ELL is cut into (one gather loop each per level)
+MAX_SLICES = 8
 
 
 def pow2_ceil(x: int) -> int:
@@ -77,6 +85,94 @@ class EllView:
     spill_src: np.ndarray    # (n_spill,) int32 COO remainder
     spill_dst: np.ndarray    # (n_spill,) int32
     cap: int
+
+
+class SlicedEll(NamedTuple):
+    """Degree-sorted sliced ELL of one direction's rows (after SELL-C-σ),
+    the table the packed MS-BFS sweep gathers over.
+
+    Vertices are renumbered by descending row degree (a stable sort):
+    position ``p`` holds vertex ``perm[p]``, and ``inv_perm[v] = p``.
+    ``tables[i]`` holds a contiguous run of positions, as wide as the
+    largest degree in it; its entries are neighbour *positions*, pad =
+    n. A run of degree-0 rows is a width-0 table that gathers nothing.
+    Bounds and widths are the tables' static shapes, so a jit over this
+    pytree sees fixed shapes per graph. About one entry per arc, against
+    ``n * pow2_ceil(max degree)`` for the padded ELL.
+    """
+
+    perm: "jax.Array"        # (n,) int32
+    inv_perm: "jax.Array"    # (n,) int32
+    tables: tuple            # ((rows_i, width_i) int32, ...) in perm order
+
+    @property
+    def widths(self) -> tuple[int, ...]:
+        return tuple(int(t.shape[1]) for t in self.tables)
+
+    @property
+    def rows_per_level(self) -> int:
+        """Frontier rows one sweep level gathers: the tables' entries."""
+        return sum(int(t.shape[0]) * int(t.shape[1]) for t in self.tables)
+
+
+def slice_bounds(deg_desc: np.ndarray,
+                 max_slices: int = MAX_SLICES) -> list[int]:
+    """Cut points ``[0, b_1, ..., n]`` of at most ``max_slices``
+    contiguous slices of rows sorted by degree, descending, that
+    minimise the entries ``sum(rows_i * width_i)`` (a slice is as wide as
+    its first row). A DP over the distinct degrees: a cut inside a run of
+    equal degrees never helps."""
+    deg = np.asarray(deg_desc, np.int64)
+    if deg.size == 0:
+        return [0, 0]
+    # distinct degrees d_0 > d_1 > ... and the row span of each
+    starts = np.flatnonzero(np.r_[True, deg[1:] != deg[:-1]])
+    ends = np.r_[starts[1:], deg.size]
+    d = deg[starts]
+    K = d.size
+    # cost[j, l]: one slice over groups j..l = (ends[l] - starts[j]) * d[j]
+    cost = (ends[None, :] - starts[:, None]) * d[:, None]
+    cost = np.where(np.arange(K)[None, :] >= np.arange(K)[:, None], cost,
+                    np.iinfo(np.int64).max // 4)
+    best = cost[0].copy()                 # one slice over groups 0..l
+    choice = [np.zeros(K, np.int64)]      # first group of the last slice
+    for _ in range(1, min(max_slices, K)):
+        # best_prev[j-1] + cost[j, l], over j in 1..l (j = 0: no cut)
+        cand = np.full((K, K), np.iinfo(np.int64).max // 4)
+        cand[1:] = best[:-1, None] + cost[1:]
+        cand[0] = cost[0]
+        j = np.argmin(cand, axis=0)
+        best = cand[j, np.arange(K)]
+        choice.append(j)
+    cuts, l = [], K - 1
+    for c in reversed(choice):
+        j = int(c[l])
+        cuts.append(int(starts[j]))
+        if j == 0:
+            break
+        l = j - 1
+    return sorted(set(cuts)) + [int(deg.size)]
+
+
+def sliced_ell(indptr: np.ndarray, indices: np.ndarray, n: int) -> tuple:
+    """Host arrays of a :class:`SlicedEll` over the CSR rows
+    ``indptr``/``indices``: ``(perm, inv_perm, tables)``, all int32."""
+    deg = np.diff(indptr).astype(np.int64)
+    perm = np.argsort(-deg, kind="stable")
+    inv_perm = np.empty(n, np.int64)
+    inv_perm[perm] = np.arange(n)
+    bounds = slice_bounds(deg[perm])
+    tables = []
+    for lo, hi in zip(bounds[:-1], bounds[1:]):
+        rows = perm[lo:hi]
+        d = deg[rows]
+        width = int(d[0]) if d.size else 0
+        tab = np.full((hi - lo, width), n, np.int32)
+        r = np.repeat(np.arange(hi - lo), d)
+        c = ragged_arange(d)
+        tab[r, c] = inv_perm[indices[np.repeat(indptr[rows], d) + c]]
+        tables.append(tab)
+    return perm.astype(np.int32), inv_perm.astype(np.int32), tuple(tables)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,6 +320,10 @@ class DeviceGraph:
     r_ell_mask: "jax.Array"
     ell_cap: int
     r_ell_cap: int
+    # the packed index sweep's tables (sweep_table); None after an
+    # incremental delta patch, which keeps only the padded ELL current
+    ell_sliced: Optional[SlicedEll] = None
+    r_ell_sliced: Optional[SlicedEll] = None
 
     @property
     def m_cap(self) -> int:
@@ -275,6 +375,12 @@ class DeviceGraph:
             cap = pow2_ceil(g.m) if edge_cap is None else int(edge_cap)
             esrc, edst = pad_edge_list(esrc, edst, g.n, cap)
             r_esrc, r_edst = pad_edge_list(r_esrc, r_edst, g.n, cap)
+
+        def sliced(indptr, indices) -> SlicedEll:
+            perm, inv_perm, tables = sliced_ell(indptr, indices, g.n)
+            return SlicedEll(jnp.asarray(perm), jnp.asarray(inv_perm),
+                             tuple(jnp.asarray(t) for t in tables))
+
         return DeviceGraph(
             n=g.n, m=g.m,
             esrc=jnp.asarray(esrc), edst=jnp.asarray(edst),
@@ -282,6 +388,8 @@ class DeviceGraph:
             r_esrc=jnp.asarray(r_esrc), r_edst=jnp.asarray(r_edst),
             r_ell_idx=jnp.asarray(rell.idx), r_ell_mask=jnp.asarray(rell.mask),
             ell_cap=ell.cap, r_ell_cap=rell.cap,
+            ell_sliced=sliced(g.indptr, g.indices),
+            r_ell_sliced=sliced(g.r_indptr, g.r_indices),
         )
 
     def direction(self, reverse: bool):
@@ -289,3 +397,12 @@ class DeviceGraph:
         if reverse:
             return self.r_ell_idx, self.r_ell_mask
         return self.ell_idx, self.ell_mask
+
+    def sweep_table(self, reverse: bool):
+        """The table a packed index sweep gathers over: the rows of
+        ``ell_idx`` (``reverse=False``) or of ``r_ell_idx``, sliced when
+        this graph carries the sliced layout, else the padded ELL."""
+        if reverse:
+            return self.r_ell_idx if self.r_ell_sliced is None \
+                else self.r_ell_sliced
+        return self.ell_idx if self.ell_sliced is None else self.ell_sliced

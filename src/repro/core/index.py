@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .graph import DeviceGraph, ragged_arange
-from .msbfs import edge_span, msbfs_dist, msbfs_dist_ell, INF_FOR
+from .msbfs import (count_sweep, edge_span, msbfs_dist, msbfs_dist_ell,
+                    INF_FOR)
 
 __all__ = ["QueryIndex", "build_index", "walk_counts", "slack_vector",
            "column_reach"]
@@ -108,10 +109,12 @@ def build_index(dg: DeviceGraph, queries: Sequence[Query],
     ``backend``: a resolved kernel backend. ``None``/``"jnp"`` runs the
     segment-op sweeps over the edge lists; ``"pallas"``/``"interpret"``
     runs the fused bit-packed ELL sweeps (``msbfs_dist_ell``) — one
-    dispatch per level, bit-equal distances. Forward distances gather the
-    reverse ELL table (in-neighbors of G) and vice versa; the ELL tables
-    are replicated even on a sharded engine, so the kernel route never
-    depends on the GSPMD edge partition.
+    dispatch per level, bit-equal distances — over ``dg.sweep_table``
+    (the sliced ELL, or the padded ELL of a delta-patched graph), each
+    counted by :func:`~repro.core.msbfs.count_sweep`. Forward distances
+    gather the reverse table (in-neighbors of G) and vice versa; the ELL
+    tables are replicated even on a sharded engine, so the kernel route
+    never depends on the GSPMD edge partition.
     """
     queries = tuple((int(s), int(t), int(k)) for s, t, k in queries)
     k_max = max(k for _, _, k in queries)
@@ -120,10 +123,13 @@ def build_index(dg: DeviceGraph, queries: Sequence[Query],
     src_col = np.searchsorted(srcs, [q[0] for q in queries]).astype(np.int32)
     tgt_col = np.searchsorted(tgts, [q[1] for q in queries]).astype(np.int32)
     if backend is not None and backend != "jnp":
-        dist_s = msbfs_dist_ell(dg.r_ell_idx, jnp.asarray(srcs),
+        r_table, table = dg.sweep_table(True), dg.sweep_table(False)
+        dist_s = msbfs_dist_ell(r_table, jnp.asarray(srcs),
                                 n=dg.n, k_max=k_max, backend=backend)
-        dist_t = msbfs_dist_ell(dg.ell_idx, jnp.asarray(tgts),
+        dist_t = msbfs_dist_ell(table, jnp.asarray(tgts),
                                 n=dg.n, k_max=k_max, backend=backend)
+        for t in (r_table, table):
+            count_sweep(t, dg.n, dg.m, k_max)
     else:
         m_valid = edge_span(dg.m, edge_chunk, dg.m_cap)
         dist_s = msbfs_dist(dg.esrc, dg.edst, jnp.asarray(srcs),

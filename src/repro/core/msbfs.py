@@ -10,10 +10,11 @@ shardable.
 Two sweeps:
   * ``msbfs_dist``     -- int8 frontier, chunked edge-list gathers (the
                           ``jnp`` kernel backend).
-  * ``msbfs_dist_ell`` -- bit-packed frontier, OR-gather over the padded
-                          ELL table (kernels/msbfs_expand; the ``pallas``
-                          and ``interpret`` backends), bit-equal to the
-                          edge-list sweep.
+  * ``msbfs_dist_ell`` -- bit-packed frontier, OR-gather over the
+                          degree-sorted sliced ELL (or the padded ELL of a
+                          delta-patched graph; kernels/msbfs_expand; the
+                          ``pallas`` and ``interpret`` backends),
+                          bit-equal to the edge-list sweep.
 
 Distances are int8 (k_max <= 120); unreached = INF = k_max + 1.
 
@@ -35,8 +36,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .graph import SlicedEll
+from ..obs import metrics as obsmetrics
+
 __all__ = ["msbfs_dist", "msbfs_set_dist", "msbfs_hop", "msbfs_dist_ell",
-           "msbfs_set_dist_ell", "INF_FOR", "edge_span", "K_MAX_INT8"]
+           "msbfs_set_dist_ell", "INF_FOR", "edge_span", "K_MAX_INT8",
+           "count_sweep", "swept"]
 
 # Largest hop budget the int8 distance representation supports. INF_FOR
 # (k_max + 1) must stay representable AND keep headroom below int8 max
@@ -169,25 +174,66 @@ def msbfs_dist(esrc: jax.Array, edst: jax.Array, sources: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# packed twins: bit-packed sweeps over the padded ELL in-neighbor table
+# packed twins: bit-packed sweeps over an ELL in-neighbour table
 # (kernels/msbfs_expand msbfs_step: expand + visited dedup per level),
 # 32 sources per uint32 word instead of one int8 byte each on the
-# segment-op path. The ELL tables are already sentinel-padded
-# to stable pow2 capacities (DeviceGraph.build), so these sweeps inherit
-# the zero-warm-retrace guarantee without edge chunking: m_valid has no
-# analogue here because sentinel rows gather the all-zero frontier row n
-# and contribute nothing.
+# segment-op path.
+#
+# The table is a DeviceGraph's sweep_table: the degree-sorted sliced ELL
+# (SlicedEll) of a fully built graph, or the padded ELL of a delta-patched
+# one. The sliced sweep runs in permuted vertex order: sources are seeded
+# at inv_perm[sources], each slice OR-gathers into its own contiguous run
+# of rows, and the distances are un-permuted once at the end. A level then
+# gathers one frontier row per table entry, about one per valid arc
+# (rows_per_level), where the padded table gathers n * pow2(max degree)
+# rows, most of them the all-zero sentinel row n. Both tables have fixed
+# shapes per graph, so these sweeps need no edge chunking: m_valid has no
+# analogue here because pad entries gather row n and contribute nothing.
+# The padded ELL stays for the delta path (rows patched in place, no
+# retrace) and for expand_level.
 #
 # Direction convention (matches msbfs_dist's edge-list arguments):
 # relaxation is next[v] = OR over in-neighbors u of v, so forward
-# distances on G take the *reverse* table dg.r_ell_idx (out-neighbors in
-# G_r == in-neighbors in G) and distances on G_r take dg.ell_idx.
+# distances on G take the *reverse* table dg.sweep_table(reverse=True)
+# (out-neighbors in G_r == in-neighbors in G) and distances on G_r take
+# dg.sweep_table(reverse=False).
 # ---------------------------------------------------------------------------
 
-def _packed_sweep(idx: jax.Array, frontier: jax.Array, S: int, k_max: int,
-                  backend: str) -> jax.Array:
+_ROWS = "engine_index_rows_total"
+_ARCS = "engine_index_arcs_total"
+
+
+def count_sweep(table, n: int, m: int, k_max: int) -> None:
+    """Count one packed sweep over ``table`` on the host, from shapes:
+    the frontier rows its levels gather (``engine_index_rows_total``,
+    labelled ``layout="sliced"|"padded"``) and the valid arcs they relax,
+    ``m`` a level (``engine_index_arcs_total``)."""
+    reg = obsmetrics.registry()
+    if isinstance(table, SlicedEll):
+        layout, rows = "sliced", table.rows_per_level
+    else:
+        layout, rows = "padded", n * int(table.shape[1])
+    reg.counter(_ROWS, layout=layout).inc(rows * k_max)
+    reg.counter(_ARCS).inc(m * k_max)
+
+
+def swept() -> tuple[int, int]:
+    """``(rows gathered, arcs relaxed)`` by every packed sweep counted so
+    far (:func:`count_sweep`), both layouts together."""
+    reg = obsmetrics.registry()
+    rows = sum(reg.counter(_ROWS, layout=lay).value
+               for lay in ("sliced", "padded"))
+    return int(rows), int(reg.counter(_ARCS).value)
+
+
+def _packed_sweep(idx, frontier: jax.Array, n: int, S: int, k_max: int,
+                  backend: str,
+                  inv_perm: Optional[jax.Array] = None) -> jax.Array:
     """Levels 1..k_max from the packed level-0 ``frontier`` (n+1, W),
-    whose row n stays 0. Returns (n+1, S) int8 distances.
+    whose row n stays 0, over ``idx``: an (n, D) in-neighbour table, or a
+    sliced ELL's tables, in which case every row index is a permuted
+    position and ``inv_perm`` un-permutes the result. Returns (n+1, S)
+    int8 distances.
 
     A vertex first reached at hop d has its bit clear in the visited sets
     of levels 0..d-1 and set from d on, so its distance is the number of
@@ -198,7 +244,7 @@ def _packed_sweep(idx: jax.Array, frontier: jax.Array, S: int, k_max: int,
     """
     from ..kernels.msbfs_expand.ops import msbfs_step, unreached_count
 
-    n, W = idx.shape[0], frontier.shape[1]
+    W = frontier.shape[1]
     visited = frontier[:n]
     count = unreached_count(visited, jnp.zeros((32 * W, n), jnp.int8),
                             backend)
@@ -209,24 +255,37 @@ def _packed_sweep(idx: jax.Array, frontier: jax.Array, S: int, k_max: int,
                                       backend=backend)
             frontier = jnp.concatenate([new, zero], axis=0)
             count = unreached_count(visited, count, backend)
+    dist = count[:S].T
+    if inv_perm is not None:
+        # vertex v's row is the sweep's row inv_perm[v]
+        dist = dist.at[inv_perm].get(mode="promise_in_bounds")
     inf = jnp.full((1, S), INF_FOR(k_max), jnp.int8)
-    return jnp.concatenate([count[:S].T, inf], axis=0)
+    return jnp.concatenate([dist, inf], axis=0)
+
+
+def _sweep_args(table, n: int):
+    """``(idx, inv_perm)`` for :func:`_packed_sweep` from a sweep table."""
+    if isinstance(table, SlicedEll):
+        return table.tables, table.inv_perm
+    return table[:n], None
 
 
 @partial(jax.jit, static_argnames=("n", "k_max", "backend"))
-def msbfs_dist_ell(ell_in_idx: jax.Array, sources: jax.Array,
+def msbfs_dist_ell(table, sources: jax.Array,
                    *, n: int, k_max: int, backend: str = "jnp") -> jax.Array:
     """Packed ELL twin of :func:`msbfs_dist`.
 
-    ell_in_idx : (n+1, D) int32 padded ELL *in*-neighbor table (pad = n;
-                 row n is the sentinel row, never expanded).
+    table      : a :class:`~repro.core.graph.SlicedEll` of the
+                 in-neighbour rows, or the padded (n[+1], D) int32 ELL
+                 *in*-neighbor table (pad = n; a row n is never expanded)
+                 — ``DeviceGraph.sweep_table``.
     sources    : (S,) int32.
     backend    : static "pallas" | "interpret" | "jnp" (resolved by the
                  caller; a registry enum's value — strings keep the jit
                  cache key plain).
     Returns (n+1, S) int8, bit-equal to :func:`msbfs_dist` on the same
-    graph (distances are set-membership facts; only the dispatch shape of
-    a level differs between backends).
+    graph whichever table it sweeps (distances are set-membership facts;
+    only the dispatch shape of a level differs between backends).
 
     Source ``i`` is bit ``i % 32`` of word ``i // 32`` of the packed
     frontier (W = ceil(S / 32) words per vertex).
@@ -235,24 +294,31 @@ def msbfs_dist_ell(ell_in_idx: jax.Array, sources: jax.Array,
     S = sources.shape[0]
     W = -(-S // 32)
     cols = jnp.arange(S)
+    idx, inv_perm = _sweep_args(table, n)
+    rows = sources if inv_perm is None else inv_perm[sources]
     # distinct columns set distinct bits, so add == or, even where a
     # source repeats; the sentinel row n is never a source
-    frontier = jnp.zeros((n + 1, W), jnp.uint32).at[sources, cols // 32].add(
+    frontier = jnp.zeros((n + 1, W), jnp.uint32).at[rows, cols // 32].add(
         jnp.uint32(1) << (cols % 32).astype(jnp.uint32))
-    return _packed_sweep(ell_in_idx[:n], frontier, S, k_max, backend)
+    return _packed_sweep(idx, frontier, n, S, k_max, backend, inv_perm)
 
 
 @partial(jax.jit, static_argnames=("n", "k_max", "backend"))
-def msbfs_set_dist_ell(ell_in_idx: jax.Array, seed_mask: jax.Array,
+def msbfs_set_dist_ell(table, seed_mask: jax.Array,
                        *, n: int, k_max: int,
                        backend: str = "jnp") -> jax.Array:
     """Packed ELL twin of :func:`msbfs_set_dist` (one bit column seeded
-    with the whole vertex set; 31 of the word's 32 bits idle).
+    with the whole vertex set; 31 of the word's 32 bits idle). ``table``
+    as for :func:`msbfs_dist_ell`.
 
     seed_mask : (n+1,) int8 in {0,1} (row n must be 0).
     Returns (n+1,) int8 bit-equal to :func:`msbfs_set_dist`.
     """
     _check_k_max(k_max)
+    idx, inv_perm = _sweep_args(table, n)
     seed = seed_mask.astype(bool).at[n].set(False)
+    if inv_perm is not None:
+        seed = jnp.concatenate([seed[table.perm], seed[n:]])
     frontier = seed.astype(jnp.uint32)[:, None]            # bit 0 of word 0
-    return _packed_sweep(ell_in_idx[:n], frontier, 1, k_max, backend)[:, 0]
+    return _packed_sweep(idx, frontier, n, 1, k_max, backend,
+                         inv_perm)[:, 0]

@@ -1,14 +1,20 @@
-"""Bit-packed MS-BFS expansion over the padded ELL table (jnp) and the
+"""Bit-packed MS-BFS expansion over an ELL table (jnp) and the
 pack/unpack helpers.
 
     next[v, w] = OR over d of frontier[ell_idx[v, d], w]
 
 Frontiers are bit-packed uint32 words, 32 BFS sources per word (the MS-BFS
-[36] trick): one OR handles 32 sources at once. The graph is padded ELL,
-so the expansion is a regular row gather, taken one ELL column at a time:
-the live gather stays (V, W) words and never becomes (V, D, W), which at
-2^22 vertices would not fit the device. The expansion has no Pallas arm
-(see ``JNP_ONLY_OPS`` in :mod:`repro.kernels.registry`); the per-level
+[36] trick): one OR handles 32 sources at once. The table is padded ELL,
+or a sliced ELL: a tuple of ELL tables over contiguous row runs, each as
+wide as its own rows need (``core/graph.py`` ``SlicedEll``). Either way
+the expansion is a regular row gather, taken one column of a table at a
+time: the live gather stays (rows, W) words and never becomes
+(rows, D, W), which at 2^22 vertices would not fit the device. Each entry
+costs one gathered row, so a level over the sliced ELL gathers about one
+row per arc, and over the padded ELL V * D rows. The index sweep takes the
+sliced ELL; the padded one remains for a delta-patched graph and for the
+enumeration's ``expand_level``. The expansion has no Pallas arm (see
+``JNP_ONLY_OPS`` in :mod:`repro.kernels.registry`); the per-level
 distance count (:func:`unreached_count_ref`) has one (kernel.py).
 
 Sentinel: ell row entries equal to V point at frontier row V, which the
@@ -42,11 +48,17 @@ def unpack_bits(words: jax.Array, S: int) -> jax.Array:
     return bits.reshape(V, W * 32)[:, :S].astype(bool)
 
 
-def msbfs_expand_ref(ell_idx: jax.Array, frontier: jax.Array) -> jax.Array:
-    """ell_idx: (V, D) int32 (pad = V); frontier: (V+1, W) uint32 (row V = 0).
+def msbfs_expand_ref(ell_idx, frontier: jax.Array) -> jax.Array:
+    """ell_idx: (V, D) int32 (pad = V), or a tuple of (rows_i, D_i) tables
+    whose rows stack to V; frontier: (V+1, W) uint32 (row V = 0).
 
     Returns next[v, w] = OR_d frontier[ell_idx[v, d], w], (V, W) uint32.
     """
+    if isinstance(ell_idx, tuple):
+        # each table fills its own contiguous run of rows: no scatter
+        return jnp.concatenate([msbfs_expand_ref(t, frontier)
+                                for t in ell_idx], axis=0)
+
     def column(acc, rows):
         return acc | frontier.at[rows].get(mode="promise_in_bounds"), None
 
@@ -58,7 +70,8 @@ def msbfs_step_ref(ell_idx: jax.Array, frontier: jax.Array,
                    visited: jax.Array):
     """One MS-BFS level: expand, then dedup against the visited set.
 
-    ell_idx  : (V, D) int32 in-neighbour table (pad = V)
+    ell_idx  : (V, D) int32 in-neighbour table (pad = V), or a sliced
+               ELL's tuple of tables (see :func:`msbfs_expand_ref`)
     frontier : (V+1, W) uint32 packed level-(hop-1) frontier (row V = 0)
     visited  : (V, W) uint32 packed reached-set (hop-0 seeds included)
 

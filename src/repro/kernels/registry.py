@@ -104,18 +104,22 @@ _OP_MODULES = {
     "flash_attention": "repro.kernels.flash_attention.ops",
 }
 
-# Ops without a Pallas arm, and why. Each is a row gather over the padded
-# ELL table: out[v] = reduce over d of src[ell_idx[v, d]]. Mosaic has no
+# Ops without a Pallas arm, and why. Each is a row gather over an ELL
+# table: out[v] = reduce over d of src[ell_idx[v, d]]. Mosaic has no
 # vector gather from a loaded value, and the source rows cannot sit in
 # VMEM: the bit-packed frontier of a 2^22-vertex graph at 512 sources is
 # 256 MiB (2 GiB with its 16-word rows padded to 128 lanes), against
-# 128 MiB of VMEM. Left in HBM, every neighbour costs one 64-byte row DMA:
-# V*D descriptors per level, issued one by one from the scalar core. XLA's
-# native gather serves the same access pattern, so these ops run their jnp
-# arm on every platform. That arm walks one ELL column per step, so the
-# live gather is (V, W) and never (V, D, W).
-_ELL_GATHER = ("row gather over the padded ELL table; no Mosaic vector "
-               "gather, source rows exceed VMEM (see registry.py)")
+# 128 MiB of VMEM. Left in HBM, every table entry costs one 64-byte row
+# DMA, issued one by one from the scalar core. XLA's native gather serves
+# the same access pattern, so these ops run their jnp arm on every
+# platform. That arm walks one table column per step, so the live gather
+# is (rows, W) and never (rows, D, W). The index sweep passes the
+# degree-sorted sliced ELL, about one entry per arc, so a level issues
+# about m descriptors; the padded (V, D) table, V*D descriptors with most
+# of them on the sentinel row, remains for a delta-patched graph and for
+# the enumeration's expand_level.
+_ELL_GATHER = ("row gather over an ELL table; no Mosaic vector gather, "
+               "source rows exceed VMEM (see registry.py)")
 JNP_ONLY_OPS: dict[str, str] = {
     "msbfs_expand": _ELL_GATHER,
     "msbfs_step": _ELL_GATHER,

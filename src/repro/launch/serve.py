@@ -40,6 +40,7 @@ from ..core import generators
 from ..core.planner import admission_fast_path
 from ..core.query import (Output, PathQuery, Planner, QueryLike, QueryResult)
 from ..core.clustering import cluster_queries
+from ..core.msbfs import swept
 from ..core.similarity import similarity_matrix
 from ..ft.scheduler import WorkStealingScheduler
 from ..obs import metrics as obsmetrics
@@ -501,6 +502,7 @@ class StreamingServer:
                           tenant=entry.query.tenant).record(w)
         with self.engine.obs.span("serve.batch",
                                   n_queries=len(batch)) as sb:
+            rows0, arcs0 = swept()
             steals_before = self.sched.steals
             failovers_before = self.sched.failovers
             requeued_before = self.sched.requeued
@@ -638,6 +640,7 @@ class StreamingServer:
             self.n_deadline_miss += n_miss
             reg.counter("serve_deadline_miss_total").inc(n_miss)
         Q = len(queries)
+        rows1, arcs1 = swept()
         self.batch_log.append({
             "wall_s": wall, "n_queries": Q, "n_clusters": len(clusters),
             "kernel_backend": self.engine.kernel_backend.value,
@@ -674,6 +677,11 @@ class StreamingServer:
             **({"per_device": per_device,
                 "n_devices": len(per_device)} if per_device else {}),
             **agg,
+            # packed index sweeps: frontier rows gathered, and valid arcs
+            # x hops relaxed (counters engine_index_rows_total{layout},
+            # engine_index_arcs_total; 0 on the edge-list backend)
+            "n_index_rows": rows1 - rows0,
+            "n_index_arcs": arcs1 - arcs0,
             **({"cache": self.engine.cache.info()}
                if self.engine.cache is not None else {}),
         })

@@ -229,8 +229,10 @@ class TestJaxprAudit:
         # satellite: the fused expand_level budget is committed
         assert "expand_level" in budgets
         # acceptance: the packed MS-BFS sweep stays at ONE kernel dispatch
-        # (the msbfs_count distance update) per level on the kernel backend
-        for fn in ("msbfs_dist_ell", "msbfs_set_dist_ell"):
+        # (the msbfs_count distance update) per level on the kernel
+        # backend, over the padded and the sliced ELL alike
+        for fn in ("msbfs_dist_ell", "msbfs_set_dist_ell",
+                   "msbfs_dist_ell_sliced", "msbfs_set_dist_ell_sliced"):
             assert budgets[fn]["interpret"][
                 "kernel_dispatches_per_level"] == 1
 

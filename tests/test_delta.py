@@ -186,6 +186,37 @@ class TestDeviceGraphUpdate:
         np.testing.assert_array_equal(np.asarray(dg2.r_ell_mask), rell.mask)
         assert dg2.m == g2.m
 
+    def test_incremental_patch_drops_sliced_layout(self):
+        """An incremental patch drops the sliced ELL: the index sweep runs
+        over the padded ELL (counted under layout="padded") and the
+        engine's answers still match the oracle; a full rebuild restores
+        the sliced layout."""
+        from repro.obs import metrics as obsmetrics
+        g = generators.community(120, n_comm=2, avg_deg=4.0, seed=31)
+        qs = generators.similar_queries(g, 4, similarity=0.8,
+                                        k_range=(3, 4), seed=32)
+        eng = BatchPathEngine(g, EngineConfig(min_cap=64,
+                                              kernel_backend="interpret"))
+        assert eng.dg.ell_sliced is not None
+        src, dst = _edge_list(g)
+        u, v = int(src[0]), int(dst[0])
+        w = next(int(x) for x in range(g.n)
+                 if x != u and x not in g.neighbors(u))
+        rep = eng.apply_delta(GraphDelta.from_pairs(add=[(u, w)],
+                                                    remove=[(u, v)]))
+        assert rep["device_update"] == "incremental"
+        assert eng.dg.ell_sliced is None and eng.dg.r_ell_sliced is None
+        assert eng.dg.sweep_table(True) is eng.dg.r_ell_idx
+        padded = obsmetrics.registry().counter("engine_index_rows_total",
+                                               layout="padded")
+        before = padded.value
+        r = eng.run(qs)
+        assert padded.value > before
+        for qi, (s, t, k) in enumerate(qs):
+            assert path_set(r[qi].paths) == path_set(
+                enumerate_paths_bruteforce(eng.g, s, t, k))
+        assert DeviceGraph.build(eng.g).ell_sliced is not None
+
     def test_cap_overflow_falls_back_to_rebuild(self):
         g = Graph.from_edges(5, [0, 1], [1, 2])   # max out-degree 1
         dg = DeviceGraph.build(g)

@@ -112,3 +112,92 @@ class TestGenerators:
         qs = generators.random_queries(g, 10, (2, 5), seed=5)
         for s, t, k in qs:
             assert bfs_dist_from(g, s, k)[t] <= k
+
+
+def _rgg(n, seed, radius_c=0.55):
+    """A DIMACS10-style random geometric graph, every edge both ways."""
+    from scipy.spatial import cKDTree
+    p = np.random.default_rng(seed).random((n, 2))
+    pairs = cKDTree(p).query_pairs(radius_c * np.sqrt(np.log(n) / n),
+                                   output_type="ndarray")
+    a, b = pairs[:, 0], pairs[:, 1]
+    return Graph.from_edges(n, np.r_[a, b], np.r_[b, a])
+
+
+def _delaunay(n, seed):
+    """A DIMACS10-style Delaunay triangulation, every edge both ways."""
+    from scipy.spatial import Delaunay
+    tri = Delaunay(np.random.default_rng(seed).random((n, 2))).simplices
+    e = np.concatenate([tri[:, [0, 1]], tri[:, [1, 2]], tri[:, [0, 2]]])
+    return Graph.from_edges(n, np.r_[e[:, 0], e[:, 1]],
+                            np.r_[e[:, 1], e[:, 0]])
+
+
+LAYOUT_GRAPHS = {
+    "random": lambda: random_graph(300, 1500, 4),
+    "powerlaw": lambda: generators.powerlaw(400, 4.0, seed=5),
+    "isolated": lambda: Graph.from_edges(50, [0, 1, 2], [1, 2, 3]),
+    "empty": lambda: Graph.from_edges(6, [], []),
+    "rgg": lambda: _rgg(4096, 1),
+    "delaunay": lambda: _delaunay(4096, 2),
+}
+
+
+class TestSlicedEll:
+    @pytest.mark.parametrize("name", sorted(LAYOUT_GRAPHS))
+    def test_layout_holds_every_arc_once(self, name):
+        """Each direction's sliced ELL: perm and inv_perm are inverse
+        permutations, rows sorted by degree, at most MAX_SLICES tables
+        as wide as their widest row, and every valid arc of the row's
+        vertex exactly once (as a renumbered position), pad = n."""
+        from repro.core.graph import MAX_SLICES
+        g = LAYOUT_GRAPHS[name]()
+        dg = DeviceGraph.build(g)
+        for sl, ip, ix in ((dg.ell_sliced, g.indptr, g.indices),
+                           (dg.r_ell_sliced, g.r_indptr, g.r_indices)):
+            perm, inv = np.asarray(sl.perm), np.asarray(sl.inv_perm)
+            np.testing.assert_array_equal(perm[inv], np.arange(g.n))
+            np.testing.assert_array_equal(inv[perm], np.arange(g.n))
+            assert 1 <= len(sl.tables) <= MAX_SLICES
+            deg = np.diff(ip)[perm]
+            assert np.all(np.diff(deg) <= 0)
+            rows = np.concatenate([
+                np.pad(np.asarray(t), ((0, 0), (0, max(sl.widths) - w)),
+                       constant_values=g.n)
+                for t, w in zip(sl.tables, sl.widths)])
+            assert rows.shape[0] == g.n
+            for t, w in zip(sl.tables, sl.widths):
+                assert w == (np.asarray(t) != g.n).sum(axis=1).max(
+                    initial=0)
+            for p in range(g.n):
+                got = rows[p][rows[p] != g.n]
+                want = inv[ix[ip[perm[p]]:ip[perm[p] + 1]]]
+                np.testing.assert_array_equal(got, want)
+            assert sl.rows_per_level == (rows != g.n).sum() + sum(
+                (np.asarray(t) == g.n).sum() for t in sl.tables)
+
+    @pytest.mark.parametrize("name", ["rgg", "delaunay"])
+    def test_sliced_rows_per_level_near_arcs(self, name):
+        """On DIMACS10-style rgg and Delaunay draws a sweep level gathers
+        at most 1.10 rows per valid arc (the padded ELL: n * pow2(max
+        degree))."""
+        g = LAYOUT_GRAPHS[name]()
+        dg = DeviceGraph.build(g)
+        for sl in (dg.ell_sliced, dg.r_ell_sliced):
+            assert g.m <= sl.rows_per_level <= 1.10 * g.m
+
+    def test_slice_bounds_minimise_entries(self):
+        """The DP's cuts against every way to cut a small degree list."""
+        from itertools import combinations
+        from repro.core.graph import slice_bounds
+        deg = np.array([9, 7, 7, 5, 4, 4, 4, 2, 1, 1, 0, 0])
+
+        def entries(b):
+            return sum((hi - lo) * deg[lo] for lo, hi in zip(b[:-1], b[1:]))
+        for k in (1, 2, 3, 8):
+            best = min(entries([0, *c, deg.size])
+                       for r in range(k)
+                       for c in combinations(range(1, deg.size), r))
+            got = slice_bounds(deg, k)
+            assert len(got) - 1 <= k and got[0] == 0 and got[-1] == deg.size
+            assert entries(got) == best

@@ -147,3 +147,109 @@ def test_walk_counts_matches_dense_dp(reverse, budget):
         want.append(c.sum())
     got = walk_counts(indptr, indices, src, slack, budget)
     np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# packed sweeps: the sliced ELL against the padded ELL and the edge lists
+# ---------------------------------------------------------------------------
+
+def _star_hub(n=70):
+    """Vertex 0 points at every other vertex and back: one row of degree
+    n-1 beside n-1 rows of degree 1."""
+    leaves = np.arange(1, n)
+    return Graph.from_edges(n, np.r_[np.zeros(n - 1, np.int64), leaves],
+                            np.r_[leaves, np.zeros(n - 1, np.int64)])
+
+
+def _isolated(n=60):
+    """A path over the even vertices; every odd vertex has no arc."""
+    ev = np.arange(0, n - 2, 2)
+    return Graph.from_edges(n, ev, ev + 2)
+
+
+def _regular(n=48, d=3):
+    """Every vertex of out- and in-degree d (a circulant)."""
+    src = np.repeat(np.arange(n), d)
+    return Graph.from_edges(n, src, (src + np.tile([1, 5, 11], n)) % n)
+
+
+def _poisson(n=90, avg=4.0, seed=3):
+    r = np.random.default_rng(seed)
+    src = np.repeat(np.arange(n), r.poisson(avg, n))
+    return Graph.from_edges(n, src, r.integers(0, n, src.size))
+
+
+SWEEP_GRAPHS = {"star_hub": _star_hub, "isolated": _isolated,
+                "regular": _regular, "poisson": _poisson}
+
+
+# each source count takes two hop budgets, one per direction, so the four
+# counts cover k_max 1..8 on every graph
+SWEEP_CASES = {1: (1, 5), 31: (2, 6), 33: (3, 7), 64: (4, 8)}
+
+
+@pytest.mark.parametrize("S", sorted(SWEEP_CASES))
+@pytest.mark.parametrize("graph", sorted(SWEEP_GRAPHS))
+def test_sliced_sweep_bit_equal(graph, S):
+    """msbfs_dist_ell over the sliced ELL == over the padded ELL == the
+    edge-list msbfs_dist, in both directions, with repeated sources
+    (W = 1 and 2 words)."""
+    from repro.core.graph import SlicedEll
+    from repro.core.msbfs import msbfs_dist_ell
+    g = SWEEP_GRAPHS[graph]()
+    dg = DeviceGraph.build(g)
+    assert isinstance(dg.sweep_table(True), SlicedEll)
+    r = np.random.default_rng(S)
+    srcs = r.integers(0, g.n, S).astype(np.int32)
+    srcs[S // 2:] = srcs[:S - S // 2][::-1]        # repeats
+    srcs = jnp.asarray(srcs)
+    for k, reverse in zip(SWEEP_CASES[S], (True, False)):
+        es, ed = (dg.esrc, dg.edst) if reverse else (dg.r_esrc, dg.r_edst)
+        padded = dg.r_ell_idx if reverse else dg.ell_idx
+        want = np.asarray(msbfs_dist(es, ed, srcs, n=g.n, k_max=k))
+        for table in (dg.sweep_table(reverse), padded):
+            got = msbfs_dist_ell(table, srcs, n=g.n, k_max=k)
+            np.testing.assert_array_equal(np.asarray(got), want,
+                                          err_msg=f"{reverse} k={k}")
+
+
+@pytest.mark.parametrize("backend,k_max", [("jnp", 1), ("interpret", 3),
+                                           ("jnp", 8)])
+@pytest.mark.parametrize("graph", sorted(SWEEP_GRAPHS))
+def test_sliced_set_sweep_bit_equal(graph, backend, k_max):
+    """msbfs_set_dist_ell over the sliced ELL == over the padded ELL ==
+    the edge-list msbfs_set_dist, in both directions."""
+    from repro.core.msbfs import msbfs_set_dist, msbfs_set_dist_ell
+    g = SWEEP_GRAPHS[graph]()
+    dg = DeviceGraph.build(g)
+    mask = np.zeros(g.n + 1, np.int8)
+    mask[[0, 3, g.n - 1]] = 1
+    mask = jnp.asarray(mask)
+    for reverse, (es, ed) in ((True, (dg.esrc, dg.edst)),
+                              (False, (dg.r_esrc, dg.r_edst))):
+        padded = dg.r_ell_idx if reverse else dg.ell_idx
+        want = np.asarray(msbfs_set_dist(es, ed, mask, n=g.n, k_max=k_max))
+        for table in (dg.sweep_table(reverse), padded):
+            got = msbfs_set_dist_ell(table, mask, n=g.n, k_max=k_max,
+                                     backend=backend)
+            np.testing.assert_array_equal(np.asarray(got), want,
+                                          err_msg=f"{reverse}")
+
+
+def test_index_counts_gathered_rows_per_layout():
+    """build_index on a kernel backend counts each sweep's gathered rows
+    under its layout and the valid arcs x hops it relaxes."""
+    from repro.core.index import build_index
+    from repro.core.msbfs import swept
+    from repro.obs import metrics as obsmetrics
+    g = _poisson()
+    dg = DeviceGraph.build(g)
+    rows = obsmetrics.registry().counter("engine_index_rows_total",
+                                         layout="sliced")
+    rows0, (all0, arcs0) = rows.value, swept()
+    build_index(dg, [(0, 5, 4), (1, 7, 3)], backend="interpret")
+    per_level = (dg.ell_sliced.rows_per_level
+                 + dg.r_ell_sliced.rows_per_level)
+    assert rows.value - rows0 == 4 * per_level
+    all1, arcs1 = swept()
+    assert all1 - all0 == 4 * per_level and arcs1 - arcs0 == 2 * 4 * g.m

@@ -84,14 +84,35 @@ def test_pallas_op_compiles_for_v5e(op, one_chip):
     assert _program_bytes(compiled) < HBM_BYTES
 
 
-def test_packed_sweep_fits_one_chip(one_chip):
-    """The whole index sweep (k=6, 512 sources) at 2^22 vertices."""
+def _sweep_compiles(table, sharding):
+    """Compile the whole index sweep (k=6, 512 sources) over ``table``."""
     from repro.core.msbfs import msbfs_dist_ell
-    args = [_spec((N + 1, D), i32, one_chip), _spec((S,), i32, one_chip)]
+    args = [table, _spec((S,), i32, sharding)]
     compiled = _compile(lambda e, s: msbfs_dist_ell(
         e, s, n=N, k_max=6, backend="pallas"), args)
     assert "tpu_custom_call" in compiled.as_text()
     assert _program_bytes(compiled) < HBM_BYTES // 2
+
+
+def test_packed_sweep_fits_one_chip(one_chip):
+    """The whole index sweep (k=6, 512 sources) at 2^22 vertices."""
+    _sweep_compiles(_spec((N + 1, D), i32, one_chip), one_chip)
+
+
+# a sliced ELL of 2^22 rows: widths of a skewed degree list, the last
+# table a degree-0 tail
+_HEAD = ((1 << 12, 64), (1 << 16, 32), (1 << 19, 24), (1 << 20, 16),
+         (1 << 20, 12), (1 << 20, 8))
+SLICES = (*_HEAD, (N - sum(r for r, _ in _HEAD) - (1 << 18), 4),
+          (1 << 18, 0))
+
+
+def test_sliced_sweep_fits_one_chip(one_chip):
+    """The same sweep over a degree-sorted sliced ELL of 2^22 rows."""
+    from repro.core.graph import SlicedEll
+    perm = _spec((N,), i32, one_chip)
+    _sweep_compiles(SlicedEll(perm, perm, tuple(_spec(s, i32, one_chip)
+                                                for s in SLICES)), one_chip)
 
 
 def test_similarity_fits_one_chip(one_chip):
