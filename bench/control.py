@@ -40,7 +40,7 @@ def control_readings(root: Path, workload: str, seed: int, batches: int
     """The compared numbers of the control on ``batches`` window batches
     of ``workload`` at ``seed``."""
     cell = harness.load_cell(root, workload)
-    n, src, dst = graphs.make(cell.config)
+    n, src, dst = graphs.make(cell.config, root / "bench" / "graphs")
     adj = Adjacency.build(n, src, dst)
     pool = qgen.window_batches(adj, cell.mix, seed)
     window = [(q, control_answer(adj, *q)) for i in range(batches)

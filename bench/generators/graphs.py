@@ -12,12 +12,21 @@ joined by a distance threshold (``rgg``) or by their Delaunay
 triangulation (``delaunay``). Every edge is an arc in both directions.
 Vertex ids follow the points' cell in a square grid of about one point
 per cell, row by row, so that ids carry spatial locality.
+
+A configuration whose ``generator`` is none of these names brings its own
+generator as a file: ``bench/graphs/<generator>.py``, which defines
+``make(**params) -> (n, src, dst)`` under the same contract, with
+``params["seed"]`` fixing the instance. A new deployment then adds files
+and leaves this module as it is.
 """
 from __future__ import annotations
 
+import importlib.util
+from pathlib import Path
+
 import numpy as np
 
-__all__ = ["GENERATORS", "rgg", "delaunay", "make"]
+__all__ = ["GENERATORS", "rgg", "delaunay", "make", "load_file"]
 
 
 def _points(seed: int, n: int) -> np.ndarray:
@@ -61,13 +70,38 @@ def delaunay(seed: int, n: int) -> tuple[int, np.ndarray, np.ndarray]:
 GENERATORS = {"rgg": rgg, "delaunay": delaunay}
 
 
-def make(config: dict) -> tuple[int, np.ndarray, np.ndarray]:
+def load_file(path: Path, attr: str):
+    """The attribute ``attr`` of the Python file ``path``, loaded as a
+    module of its own: how the benchmark finds a configuration's generator
+    and a per-layer metric's reader by name."""
+    module = f"bench_{path.parent.name}_{path.stem}"
+    spec = importlib.util.spec_from_file_location(
+        module.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return getattr(mod, attr)
+
+
+def make(config: dict, graph_dir: Path
+         ) -> tuple[int, np.ndarray, np.ndarray]:
     """The arc list of ``config``'s graph: the generator named by
     ``config["generator"]`` called with ``config["params"]``, whose
     ``seed`` fixes the instance. Every run of a configuration serves the
-    same instance, as every user of a published instance does."""
+    same instance, as every user of a published instance does.
+
+    The name is a built-in of :data:`GENERATORS` or the file
+    ``<graph_dir>/<name>.py``; a name that is both, or neither, raises
+    ``ValueError``."""
     name = config["generator"]
-    if name not in GENERATORS:
-        raise ValueError(f"unknown graph generator {name!r}; known: "
-                         f"{sorted(GENERATORS)}")
-    return GENERATORS[name](**config["params"])
+    path = Path(graph_dir) / f"{name}.py"
+    files = sorted(p.stem for p in Path(graph_dir).glob("*.py"))
+    if name in GENERATORS and name in files:
+        raise ValueError(f"graph generator {name!r} is both a built-in and "
+                         f"the file {path}")
+    if name in GENERATORS:
+        return GENERATORS[name](**config["params"])
+    if name not in files:
+        raise ValueError(f"unknown graph generator {name!r}; built-in: "
+                         f"{sorted(GENERATORS)}, files in {graph_dir}: "
+                         f"{files}")
+    return load_file(path, "make")(**config["params"])

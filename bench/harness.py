@@ -4,14 +4,20 @@ window, check every answer against the plain reference, report.
 Everything that belongs to a cell is found by name from ``BENCHMARK.json``:
 the configuration file it names (``configs``), the traffic mix
 ``bench/traffic/<traffic>.json`` and one reader per per-layer metric,
-``bench/metrics/<metric>.py``. Adding a configuration, a mix or a metric
-adds files and entries; this module does not change.
+``bench/metrics/<metric>.py``. A configuration names its graph generator,
+a built-in of ``generators/graphs.py`` or its own ``bench/graphs/<name>.py``.
+Adding a configuration, a mix or a metric adds files and entries; this
+module does not change.
 
 The served path is the program's ``StreamingServer`` with one replica
 group and ``AdmissionPolicy(max_batch=<batch>, max_delay_s=0)`` in front
-of a ``BatchPathEngine`` with its default configuration. The loop is
-closed: a batch is submitted, ``drain()``-ed and its answers read on the
-host before the next one is sent. Set-up serves the mix's warm-up
+of a ``BatchPathEngine``. A configuration file may state the settings of
+its deployment: the ``EngineConfig`` fields of :data:`SETTINGS`
+(``"engine_config"``) and the server's planner (``"planner"``, a
+``Planner`` name); without them the engine runs its defaults under
+``Planner.BATCH``. The loop is closed: a batch is submitted,
+``drain()``-ed and its answers read on the host before the next one is
+sent. Set-up serves the mix's warm-up
 batches; the window serves a fixed set of fresh batches in turn, whole
 batches, lets the batch in flight at ``seconds`` finish and counts it.
 Graph and batches are the same for every seed, which orders the queries
@@ -24,9 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
-import logging
 import shutil
 import sys
 import time
@@ -39,14 +43,17 @@ from generators import graphs, queries as qgen
 from kernel_bytes import index_bytes
 from reference import Adjacency, answer as reference_answer
 
-__all__ = ["Cell", "load_cell", "load_reader", "run", "compare",
-           "WindowContext", "E2E"]
+__all__ = ["Cell", "load_cell", "load_reader", "engine_settings", "run",
+           "compare", "WindowContext", "E2E"]
 
 # answers compared with the reference, at most; more are sampled from the
 # seed (a 51 s window holds far fewer at the sizes benchmarked)
 MAX_CHECKED = 4096
 CHECK_LIMITS = {"wrong_paths": 0, "wrong_counts": 0, "wrong_exists": 0,
                 "missing": 0}
+# the EngineConfig fields a configuration may set, with their JSON type:
+# settings a deployment states, not how the program is built or traced
+SETTINGS = {"cache_bytes": int}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,12 +85,8 @@ def load_cell(root: Path, workload: str) -> Cell:
 
 def load_reader(root: Path, name: str) -> Callable:
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return graphs.load_file(root / "bench" / "metrics" / f"{name}.py",
+                            "read")
 
 
 @dataclasses.dataclass
@@ -146,6 +149,31 @@ E2E = {
     "qps": lambda w: w["n_answered"] / w["window_s"],
     "setup_s": lambda w: w["setup_s"],
 }
+
+
+def engine_settings(config: dict, planner_cls) -> tuple[dict, object]:
+    """The ``EngineConfig`` overrides and the ``Planner`` that ``config``
+    states under ``"engine_config"`` and ``"planner"``: ``({}, BATCH)``
+    without them. An override names a field of :data:`SETTINGS` and holds
+    a value of its type; the planner is a member name. Anything else
+    raises ``ValueError`` naming the key."""
+    overrides = config.get("engine_config", {})
+    if not isinstance(overrides, dict):
+        raise ValueError("engine_config must be an object of EngineConfig "
+                         "fields")
+    for key, value in overrides.items():
+        if key not in SETTINGS:
+            raise ValueError(f"engine_config key {key!r} is not one of the "
+                             f"settings a configuration may state: "
+                             f"{sorted(SETTINGS)}")
+        if type(value) is not SETTINGS[key]:
+            raise ValueError(f"engine_config key {key!r}: {value!r} is not "
+                             f"a {SETTINGS[key].__name__}")
+    name = config.get("planner", "BATCH")
+    if name not in planner_cls.__members__:
+        raise ValueError(f"planner {name!r} is not one of "
+                         f"{list(planner_cls.__members__)}")
+    return dict(overrides), planner_cls[name]
 
 
 def _device_bytes(dev) -> int:
@@ -214,27 +242,28 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     from repro.core import compilelog
     from repro.core.engine import BatchPathEngine, EngineConfig
     from repro.core.graph import Graph
+    from repro.core.query import Planner
     from repro.launch.serve import AdmissionPolicy, StreamingServer
     from repro.obs import trace as obstrace
 
-    n, asrc, adst = graphs.make(cell.config)
+    overrides, planner = engine_settings(cell.config, Planner)
+    n, asrc, adst = graphs.make(cell.config, root / "bench" / "graphs")
     # the query stream's own CSR: the walks that draw queries read it
     walks = Adjacency.build(n, asrc, adst)
     g = Graph.from_edges(n, asrc, adst)
-    cfg = (EngineConfig(trace=True, trace_fence=True, trace_annotations=True)
-           if trace else EngineConfig())
-    engine = BatchPathEngine(g, cfg)
+    traced = (dict(trace=True, trace_fence=True, trace_annotations=True)
+              if trace else {})
+    engine = BatchPathEngine(g, EngineConfig(**overrides, **traced))
     jax.block_until_ready((engine.dg.ell_idx, engine.dg.r_ell_idx))
     say(f"graph: n={n} m={walks.m}, ELL {engine.dg.ell_cap}/"
-        f"{engine.dg.r_ell_cap}, host set-up "
+        f"{engine.dg.r_ell_cap}, engine_config {overrides}, planner "
+        f"{planner.name}, host set-up "
         f"{time.perf_counter() - t_start:.3f} s")
     mix = cell.mix
     srv = StreamingServer(engine, n_groups=1, policy=AdmissionPolicy(
-        max_batch=int(mix["batch"]), max_delay_s=0.0))
+        max_batch=int(mix["batch"]), max_delay_s=0.0), planner=planner)
     clock = time.perf_counter
     log = compilelog.enable()
-    # compile logging also turns on the persistent cache's per-hit log
-    logging.getLogger("jax._src.compiler").setLevel(logging.ERROR)
     warm = qgen.warm_batches(walks, mix)
     for b in warm:
         _serve(srv, b)

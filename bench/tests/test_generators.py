@@ -1,6 +1,7 @@
 """Each configuration's graph generator: deterministic per seed, at its
 published size, and the published model at a size a brute force can
 check."""
+import hashlib
 import json
 from pathlib import Path
 
@@ -11,6 +12,7 @@ from generators import graphs
 from reference import Adjacency
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+GRAPHS = Path(__file__).resolve().parents[1] / "graphs"
 
 
 def _config(name):
@@ -20,11 +22,11 @@ def _config(name):
 @pytest.mark.parametrize("name", ["rgg-n21", "delaunay-n20"])
 def test_deterministic_per_seed(name):
     cfg = dict(_config(name), params=dict(_config(name)["params"], n=4096))
-    n, src, dst = graphs.make(cfg)
-    n2, src2, dst2 = graphs.make(cfg)
+    n, src, dst = graphs.make(cfg, GRAPHS)
+    n2, src2, dst2 = graphs.make(cfg, GRAPHS)
     assert n == n2 and np.array_equal(src, src2) and np.array_equal(dst, dst2)
     other = dict(cfg, params=dict(cfg["params"], seed=2**31 + 12345))
-    _, src3, _ = graphs.make(other)
+    _, src3, _ = graphs.make(other, GRAPHS)
     assert not np.array_equal(src, src3)
 
 
@@ -36,13 +38,32 @@ def test_published_size(name, log_n, rel, ell):
     maximum degree inside the ELL width the configuration states (the
     program sizes the width to the power of two at or above it)."""
     cfg = _config(name)
-    n, src, dst = graphs.make(cfg)
+    n, src, dst = graphs.make(cfg, GRAPHS)
     assert n == cfg["published"]["n"] == cfg["params"]["n"] == 1 << log_n
     adj = Adjacency.build(n, src, dst)
     assert adj.m == src.size             # no self-loop, no repeated arc
     assert abs(adj.m / 2 / cfg["published"]["edges"] - 1) < rel
     assert np.array_equal(np.sort(src * n + dst), np.sort(dst * n + src))
     assert ell // 2 < np.diff(adj.out_ptr).max() <= ell
+
+
+@pytest.mark.parametrize("name,digest", [
+    ("rgg-n21",
+     "62e77ff285f410c6a74c229c6546a1443d9bafe4b22ee13d93c4e81e11d34d7b"),
+    ("delaunay-n20",
+     "0ccb3fd7511e46ed089ca256de3b767df22637a1274adb7e679dc00519421fc3"),
+])
+def test_committed_instance_is_pinned(name, digest):
+    """The committed configurations' generator and params, at n = 4096,
+    draw the same arcs as when the digests were taken: sha256 of n, src
+    and dst as int64 bytes."""
+    cfg = _config(name)
+    n, src, dst = graphs.make(dict(cfg, params=dict(cfg["params"], n=4096)),
+                              GRAPHS)
+    h = hashlib.sha256(np.array([n], np.int64).tobytes())
+    h.update(np.ascontiguousarray(src, np.int64).tobytes())
+    h.update(np.ascontiguousarray(dst, np.int64).tobytes())
+    assert h.hexdigest() == digest
 
 
 def _points(seed, n):
@@ -77,4 +98,4 @@ def test_delaunay_is_a_triangulation():
 
 def test_unknown_generator_is_refused():
     with pytest.raises(ValueError, match="unknown graph generator"):
-        graphs.make({"generator": "nope", "params": {}})
+        graphs.make({"generator": "nope", "params": {}}, GRAPHS)
